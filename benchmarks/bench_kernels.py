@@ -1,68 +1,47 @@
-"""Kernel benchmarks: interpret-mode correctness timing + the HBM-traffic
-model that predicts the TPU win of the overlap-fused kernels."""
+"""Kernel benchmarks: each Pallas kernel compiled for the TPU at its
+realistic shape (``repro.kernels.cases``), checked against its oracle,
+plus the HBM-traffic model that predicts the overlap-fused kernels' win.
+
+Rows are named by device kind. There is no CPU path: on any other
+backend the rows fail (interpret mode is for the CPU tests only)."""
 from __future__ import annotations
 
-import time
 from typing import List
 
 import jax
-import jax.numpy as jnp
 
-from repro.kernels.flash_attn import (attention_ref, flash_attention_op,
-                                      hbm_bytes_flash, hbm_bytes_unfused)
-from repro.kernels.fused_mlp import (fused_mlp_op, fused_mlp_ref,
-                                     hbm_bytes_fused)
+from repro.kernels.cases import check, kernel_cases
+from repro.kernels.flash_attn import hbm_bytes_flash, hbm_bytes_unfused
+from repro.kernels.fused_mlp import hbm_bytes_fused
 from repro.kernels.fused_mlp.ops import hbm_bytes_unfused as \
     mlp_bytes_unfused
-from repro.kernels.ssd_scan import ssd_ref, ssd_scan_op
 from .common import csv_row, timed
 
 
+def _hbm_saved(case) -> str:
+    """Modelled share of HBM bytes the fusion saves at the case's shape."""
+    if case.name == "fused_mlp":
+        (m, k), f = case.args[0].shape, case.args[1].shape[1]
+        saved = 1 - hbm_bytes_fused(m, k, f) / mlp_bytes_unfused(m, k, f)
+    elif case.name == "flash_attention":
+        (bh, sq, hd), skv = case.args[0].shape, case.args[1].shape[1]
+        saved = 1 - hbm_bytes_flash(bh, sq, skv, hd) / \
+            hbm_bytes_unfused(bh, sq, skv, hd)
+    else:
+        return ""
+    return f";hbm_saved={saved:.2f}"
+
+
 def kernels() -> List[str]:
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise RuntimeError(f"kernel rows need a TPU; found {dev.platform}")
+    kind = dev.device_kind.replace(" ", "_")
     rows = []
-    # fused MLP: granite_8b-like shard shapes (m=2048 tokens, k=4096,
-    # f=14336/16)
-    m, k, f = 512, 512, 1024
-    ks = jax.random.split(jax.random.PRNGKey(0), 4)
-    x = jax.random.normal(ks[0], (m, k), jnp.float32) * 0.3
-    w1 = jax.random.normal(ks[1], (k, f), jnp.float32) * 0.05
-    w3 = jax.random.normal(ks[2], (k, f), jnp.float32) * 0.05
-    w2 = jax.random.normal(ks[3], (f, k), jnp.float32) * 0.05
-    us, y = timed(lambda: fused_mlp_op(x, w1, w3, w2, tm=128, tf=256,
-                                       interpret=True).block_until_ready())
-    err = float(jnp.abs(y - fused_mlp_ref(x, w1, w3, w2)).max())
-    M, K, F = 2048, 4096, 14336 // 16
-    saved = 1 - hbm_bytes_fused(M, K, F) / mlp_bytes_unfused(M, K, F)
-    rows.append(csv_row("kernel_fused_mlp_interpret", us,
-                        f"max_err={err:.2e};"
-                        f"hbm_saved_at_granite8b_shard={saved:.2f}"))
-
-    # flash attention: 4k-train-like tile
-    q = jax.random.normal(ks[0], (8, 512, 64), jnp.float32)
-    kk = jax.random.normal(ks[1], (4, 512, 64), jnp.float32)
-    vv = jax.random.normal(ks[2], (4, 512, 64), jnp.float32)
-    us, ya = timed(lambda: flash_attention_op(
-        q, kk, vv, causal=True, tq=128, tk=128,
-        interpret=True).block_until_ready())
-    err = float(jnp.abs(ya - attention_ref(q, kk, vv)).max())
-    BH, SQ, SK, HD = 7 * 16, 4096, 4096, 128  # llava shard, train_4k
-    saved = 1 - hbm_bytes_flash(BH, SQ, SK, HD) / \
-        hbm_bytes_unfused(BH, SQ, SK, HD)
-    rows.append(csv_row("kernel_flash_attn_interpret", us,
-                        f"max_err={err:.2e};"
-                        f"hbm_saved_at_llava_train={saved:.2f}"))
-
-    # SSD scan: mamba2-780m head geometry
-    BHs, S, P, N = 4, 256, 64, 128
-    xs = jax.random.normal(ks[0], (BHs, S, P), jnp.float32)
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (BHs, S, 1)))
-    a = -jnp.exp(jax.random.normal(ks[2], (BHs, 1, 1)) * 0.2)
-    bm = jax.random.normal(ks[3], (BHs, S, N))
-    cm = jax.random.normal(ks[0], (BHs, S, N))
-    us, ys = timed(lambda: ssd_scan_op(
-        xs, dt, a, bm, cm, chunk=64,
-        interpret=True).block_until_ready())
-    err = float(jnp.abs(ys - ssd_ref(xs, dt, a, bm, cm)).max())
-    rows.append(csv_row("kernel_ssd_scan_interpret", us,
-                        f"max_err={err:.2e};chunk=64"))
+    for case in kernel_cases():
+        inputs, _, err = check(case)      # compiles outside the timing
+        us, _ = timed(lambda: case.op(*inputs).block_until_ready(),
+                      repeats=20)
+        rows.append(csv_row(f"kernel_{case.name}[{kind}]", us,
+                            f"rel_err={err:.2e}{_hbm_saved(case)}"))
     return rows

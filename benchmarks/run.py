@@ -213,6 +213,8 @@ def bench_main(argv=()) -> None:
     args = _obs_flags(argparse.ArgumentParser(
         prog="run.py bench",
         description="Paper-figure CSV suite.")).parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     finish_obs = _setup_obs(args)
     try:
         _bench_suite()
@@ -223,7 +225,7 @@ def bench_main(argv=()) -> None:
 def _bench_suite() -> None:
     # one function per paper table/figure
     from benchmarks import (bench_kernels, bench_search, bench_serve,
-                            paper_figs, roofline_report)
+                            paper_figs)
 
     benches = [
         bench_search.scoring_throughput,
@@ -255,12 +257,6 @@ def _bench_suite() -> None:
         except Exception as e:  # keep the suite going; report at the end
             failures += 1
             print(f"{bench.__name__},0.000,ERROR:{e!r}", flush=True)
-    # roofline rows come from the dry-run artifacts (if present)
-    try:
-        for row in roofline_report.roofline_rows("16x16"):
-            print(row, flush=True)
-    except Exception as e:
-        print(f"roofline_report,0.000,ERROR:{e!r}", flush=True)
     print(f"# total_wall_s={time.time() - t0:.1f} failures={failures}",
           flush=True)
     if failures:

@@ -24,7 +24,7 @@ same record sequence — and therefore the byte-identical Pareto
 frontier — as one worker or the serial path (differentially tested in
 ``tests/test_dse_distrib.py``; DESIGN.md Section 10).
 
-Worker placement is orthogonal: ``worker_mode="process"`` forks local
+Worker placement is orthogonal: ``worker_mode="process"`` spawns local
 worker processes (the ``--distributed N`` CLI), ``"thread"`` runs them
 in-process (tests, and sweeps whose cost is outside the GIL),
 ``"external"`` spawns none and waits for ``dse-worker`` processes —
@@ -173,10 +173,10 @@ def _spawn_workers(dist: DistribConfig) -> List:
             handles.append(t)
         return handles
     import multiprocessing
-    try:                       # fork shares the warmed interpreter
-        ctx = multiprocessing.get_context("fork")
-    except ValueError:         # pragma: no cover - non-POSIX fallback
-        ctx = multiprocessing.get_context("spawn")
+    # spawn, not fork: a forked child would inherit whatever the parent
+    # holds — threads, and an initialised accelerator backend that one
+    # process at a time may own — so workers start from a fresh import
+    ctx = multiprocessing.get_context("spawn")
     gate = None if slots is None else ctx.Semaphore(slots)
     for _ in range(dist.n_workers):
         p = ctx.Process(target=worker_entry,

@@ -265,8 +265,12 @@ class _Evaluator:
         self._pool = None
         if dcfg.workers > 0:
             import concurrent.futures
+            import multiprocessing
+            # spawned, never forked from a process that may hold threads
+            # or an accelerator backend (see distrib.coordinator)
             self._pool = concurrent.futures.ProcessPoolExecutor(
-                max_workers=dcfg.workers)
+                max_workers=dcfg.workers,
+                mp_context=multiprocessing.get_context("spawn"))
 
     def close(self) -> None:
         if self._pool is not None:

@@ -1,8 +1,8 @@
 """FlashAttention (Pallas TPU), causal + GQA.
 
-The dry-run roofline showed the einsum-based online-softmax attention is
-memory-bound at 32k: the [Sq, chunk] score tensors round-trip to HBM
-between the two dots. This kernel keeps scores, running max and
+The einsum-based online-softmax attention round-trips the [Sq, chunk]
+score tensors to HBM between the two dots, which makes it memory-bound
+at long context. This kernel keeps scores, running max and
 normalizer in VMEM across the KV sweep (grid minor axis), writing only
 the [Sq, hd] output — the paper's producer->consumer overlap applied to
 the QK^T -> softmax -> AV chain.

@@ -13,9 +13,13 @@ to one (m, j) grid step; "ready-time" = the producer block's grid step,
 which immediately precedes its consumption).
 
 HBM traffic: x read F_tiles times, W1/W3/W2 read once, y written once —
-vs the unfused 2x(d_ff intermediate) + weights. With tm=256, tf=512 on
-granite_8b shapes this removes ~45% of MLP HBM bytes (see
-benchmarks/bench_kernels.py).
+vs the unfused 2x(d_ff intermediate) + weights (traffic model in
+``ops.hbm_bytes_fused``).
+
+VMEM: every block is double-buffered, plus the fp32 accumulator. At
+granite_8b's width (K=4096, bf16) with tm=256, tf=512 that is 36 MiB, over
+the compiler's default scoped limit (16 MiB on v5e), so the kernel asks
+for what its tiles need (``vmem_bytes``); v5e has 128 MiB of VMEM.
 """
 from __future__ import annotations
 
@@ -49,6 +53,13 @@ def _kernel(x_ref, w1_ref, w3_ref, w2_ref, o_ref, acc_ref, *,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
+def vmem_bytes(tm: int, tf: int, k: int, itemsize: int) -> int:
+    """VMEM the kernel's blocks take: x, W1, W3, W2 and y tiles, each
+    double-buffered, plus the fp32 accumulator."""
+    blocks = (tm * k + 3 * k * tf + tm * k) * itemsize
+    return 2 * blocks + tm * k * 4
+
+
 def fused_mlp(x, w1, w3, w2, *, tm: int = 128, tf: int = 512,
               interpret: bool = False):
     """x [M, K]; w1/w3 [K, F]; w2 [F, K] -> [M, K]."""
@@ -58,6 +69,8 @@ def fused_mlp(x, w1, w3, w2, *, tm: int = 128, tf: int = 512,
     tf = min(tf, f)
     assert m % tm == 0 and f % tf == 0, (m, tm, f, tf)
     grid = (m // tm, f // tf)
+    # headroom for Mosaic's own internal scratch
+    limit = vmem_bytes(tm, tf, k, x.dtype.itemsize) + (4 << 20)
     return pl.pallas_call(
         functools.partial(_kernel, n_ftiles=grid[1]),
         grid=grid,
@@ -70,5 +83,6 @@ def fused_mlp(x, w1, w3, w2, *, tm: int = 128, tf: int = 512,
         out_specs=pl.BlockSpec((tm, k), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, k), x.dtype),
         scratch_shapes=[pltpu.VMEM((tm, k), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=limit),
         interpret=interpret,
     )(x, w1, w3, w2)

@@ -30,33 +30,38 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
 
     x = x_ref[0].astype(jnp.float32)          # [L, P]
     dt = dt_ref[0].astype(jnp.float32)        # [L, 1]
-    a = a_ref[0, 0, 0].astype(jnp.float32)    # scalar (negative)
+    a = a_ref[0].astype(jnp.float32)          # [1, 1] (negative)
     bm = b_ref[0].astype(jnp.float32)         # [L, N]
     cm = c_ref[0].astype(jnp.float32)         # [L, N]
 
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = rows >= cols
+    # Mosaic has no cumsum: the inclusive prefix sum is a lower-triangular
+    # matmul, taken over [L, L] copies so that every operand is tile-shaped.
+    # cum_sq[i, j] = cum_i and its transpose cum_sq.T[i, j] = cum_j.
     da = dt * a                               # [L, 1]
-    cum = jnp.cumsum(da, axis=0)              # [L, 1]
+    da_sq = jnp.broadcast_to(da, (chunk, chunk))
+    cum_sq = jnp.dot(causal.astype(jnp.float32), da_sq,
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
+    cum = cum_sq[:, :1]                       # [L, 1]
+    total = jnp.sum(da, axis=0, keepdims=True)    # [1, 1] = cum_{L-1}
     # intra-chunk: M[i,j] = (C_i . B_j) exp(cum_i - cum_j) dt_j, i >= j
     scores = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    seg = cum - cum[:, 0][None, :]            # [L, L] (cum_i - cum_j)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.where(rows >= cols, jnp.exp(seg), 0.0)
-    m = scores * decay * dt[:, 0][None, :]
-    y = jax.lax.dot_general(m, x, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+    decay = jnp.where(causal, jnp.exp(cum_sq - cum_sq.T), 0.0)
+    dt_cols = jnp.broadcast_to(dt, (chunk, chunk)).T  # [i, j] = dt_j
+    m = scores * decay * dt_cols
+    y = jnp.dot(m, x, preferred_element_type=jnp.float32)
 
     # inter-chunk: y += (C exp(cum)) @ state_prev ; state update
     state = state_ref[...]                    # [N, P]
-    y += jax.lax.dot_general(cm * jnp.exp(cum), state,
-                             (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    dec_state = jnp.exp(cum[-1, 0] - cum[:, 0])[:, None]   # [L, 1]
-    sc = jax.lax.dot_general(bm * (dec_state * dt), x,
-                             (((0,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    state_ref[...] = state * jnp.exp(cum[-1, 0]) + sc
+    y += jnp.dot(cm * jnp.exp(cum), state,
+                 preferred_element_type=jnp.float32)
+    wb = bm * (jnp.exp(total - cum) * dt)     # [L, N]
+    sc = jnp.dot(wb.T, x, preferred_element_type=jnp.float32)
+    state_ref[...] = state * jnp.exp(total) + sc
     y_ref[0] = y.astype(y_ref.dtype)
 
 

@@ -1,25 +1,14 @@
-"""Production meshes.
+"""Device meshes.
 
 Defined as FUNCTIONS so importing this module never touches jax device
-state (the dry-run sets XLA_FLAGS before any jax import; tests see the
-default single device).
-
-Production target: TPU v5e pods — 16x16 = 256 chips per pod, 2 pods via
-DCN for the multi-pod dry-run. Axes: ("data", "model") single-pod;
-("pod", "data", "model") multi-pod, with "pod" used as an outer
-data-parallel (or pipeline-stage) axis.
+state. Axes: ("data", "model").
 """
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+from jax.sharding import AxisType
 
 
 def make_host_mesh(data: Optional[int] = None, model: int = 1):
@@ -27,7 +16,11 @@ def make_host_mesh(data: Optional[int] = None, model: int = 1):
     n = len(jax.devices())
     if data is None:
         data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    # jax.make_mesh defaults to Explicit axes, under which the models'
+    # embedding gathers raise ShardingTypeError; they are written for Auto
+    # (compiler-propagated) sharding.
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def data_axes(mesh) -> Tuple[str, ...]:

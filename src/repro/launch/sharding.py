@@ -144,13 +144,6 @@ def opt_specs(param_spec_tree: PyTree, shapes: PyTree, mesh: Mesh,
         is_leaf=lambda x: isinstance(x, P))
 
 
-def fsdp_param_specs(tree: PyTree, mesh: Mesh) -> PyTree:
-    base = param_specs(tree, mesh)
-    return jax.tree_util.tree_map(
-        lambda s, x: zero_extend(s, x.shape, mesh), base, tree,
-        is_leaf=lambda x: isinstance(x, P))
-
-
 def batch_specs(cfg: ModelConfig, batch: int, mesh: Mesh,
                 kind: str) -> PyTree:
     dp = data_axes(mesh)

@@ -4,8 +4,8 @@
         --steps 50 --ckpt /tmp/ck_olmo
 
 Builds the host mesh, instantiates the fault-tolerant Trainer (auto-
-resumes from --ckpt if a checkpoint exists) and runs. Full-size configs
-are intended for real accelerator fleets; --smoke selects the reduced
+resumes from --ckpt if a checkpoint exists) and runs. The arch's
+published config is the default; --smoke selects its reduced
 same-family config for CPU runs.
 """
 import argparse
@@ -13,6 +13,7 @@ import logging
 
 from repro.configs import ARCH_IDS, get_config
 from repro.data.synthetic import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.train.optimizer import OptimizerConfig
 from repro.train.trainer import Trainer, TrainerConfig
@@ -21,7 +22,8 @@ from repro.train.trainer import Trainer, TrainerConfig
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo_1b", choices=list(ARCH_IDS))
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced same-family config (CPU-test scale)")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -31,6 +33,7 @@ def main():
     args = ap.parse_args()
 
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(message)s")
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     mesh = make_host_mesh(model=args.model_parallel)
     trainer = Trainer(

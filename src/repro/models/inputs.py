@@ -1,34 +1,12 @@
-"""Batch construction: real arrays for tests/training, ShapeDtypeStructs
-for the dry-run (no allocation)."""
+"""Batch construction: random token batches for tests and training."""
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+from typing import Dict
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .common import ModelConfig
-
-PyTree = Any
-
-
-def train_batch_shapes(cfg: ModelConfig, batch: int,
-                       seq: int) -> Dict[str, jax.ShapeDtypeStruct]:
-    out = {
-        "tokens": jax.ShapeDtypeStruct((batch, seq), jnp.int32),
-        "labels": jax.ShapeDtypeStruct((batch, seq), jnp.int32),
-    }
-    if cfg.family == "audio":
-        out["frames"] = jax.ShapeDtypeStruct(
-            (batch, cfg.enc_frames, cfg.d_model),
-            jnp.dtype(cfg.compute_dtype))
-    return out
-
-
-def decode_token_shapes(cfg: ModelConfig,
-                        batch: int) -> jax.ShapeDtypeStruct:
-    return jax.ShapeDtypeStruct((batch,), jnp.int32)
 
 
 def make_train_batch(cfg: ModelConfig, batch: int, seq: int,

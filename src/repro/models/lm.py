@@ -71,7 +71,7 @@ def init_params(cfg: ModelConfig, key) -> PyTree:
 
 
 def param_shapes(cfg: ModelConfig) -> PyTree:
-    """ShapeDtypeStruct tree without allocating (dry-run input)."""
+    """ShapeDtypeStruct tree without allocating (compile-test input)."""
     return jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
 
 

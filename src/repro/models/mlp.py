@@ -41,7 +41,7 @@ def mlp(cfg: ModelConfig, params: Dict, x):
 # ---------------------------------------------------------------------------
 # MoE: top-k routing, capacity-bounded einsum dispatch (GShard formulation).
 # Experts shard on the "model" mesh axis (expert parallelism); the dispatch
-# einsums lower to all-to-all-free sharded matmuls on the dry-run mesh.
+# einsums lower to all-to-all-free sharded matmuls on a sharded mesh.
 # ---------------------------------------------------------------------------
 
 def init_moe(cfg: ModelConfig, key, dtype=jnp.float32) -> Dict:

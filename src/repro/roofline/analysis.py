@@ -1,13 +1,14 @@
-"""Three-term roofline from a compiled dry-run artifact.
+"""Three-term roofline of a compiled program.
 
     compute term    = HLO_FLOPs   / (chips * peak_FLOP/s)
     memory term     = HLO_bytes   / (chips * HBM_bw)
     collective term = coll_bytes  / (chips * link_bw)
 
-``cost_analysis()`` supplies FLOPs and bytes accessed; collective bytes
-are NOT in cost_analysis, so we parse the post-optimization HLO text and
-sum operand bytes of every all-gather / all-reduce / reduce-scatter /
-all-to-all / collective-permute.
+FLOPs, bytes and collective bytes are meant to come from
+``hlo_cost.analyze`` of a compiled program's post-optimization HLO text.
+No entry point builds a ``Roofline`` yet: the chip benchmark is to
+report each scoring kernel's roofline share from these terms (ROADMAP,
+Open items 1.1), and only ``tests/test_roofline.py`` calls it today.
 
 Hardware constants (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM,
 ~50 GB/s/link ICI.
@@ -15,67 +16,11 @@ Hardware constants (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM,
 from __future__ import annotations
 
 import dataclasses
-import re
-from typing import Dict, List, Optional, Tuple
+from typing import Dict
 
 PEAK_FLOPS = 197e12          # bf16 per chip
 HBM_BW = 819e9               # bytes/s per chip
 ICI_BW = 50e9                # bytes/s per link
-
-_DTYPE_BYTES = {
-    "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
-    "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8,
-    "c64": 8, "c128": 16, "s4": 1, "u4": 1, "f8e4m3fn": 1, "f8e5m2": 1,
-}
-
-_COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter",
-                "all-to-all", "collective-permute")
-
-_SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-
-
-def _shape_bytes(shape_str: str) -> int:
-    """Total bytes of all arrays in an HLO result type string."""
-    total = 0
-    for m in _SHAPE_RE.finditer(shape_str):
-        dt, dims = m.group(1), m.group(2)
-        if dt not in _DTYPE_BYTES:
-            continue
-        n = 1
-        for d in dims.split(","):
-            if d:
-                n *= int(d)
-        total += n * _DTYPE_BYTES[dt]
-    return total
-
-
-@dataclasses.dataclass
-class CollectiveStats:
-    counts: Dict[str, int]
-    bytes_by_kind: Dict[str, int]
-
-    @property
-    def total_bytes(self) -> int:
-        return sum(self.bytes_by_kind.values())
-
-
-def parse_collectives(hlo_text: str) -> CollectiveStats:
-    counts = {k: 0 for k in _COLLECTIVES}
-    nbytes = {k: 0 for k in _COLLECTIVES}
-    for line in hlo_text.splitlines():
-        s = line.strip()
-        # result = TYPE collective-op(...)
-        m = re.match(r"(?:ROOT\s+)?[%\w.\-]+\s*=\s*(\([^)]*\)|[^=(]+?)\s+"
-                     r"(all-gather|all-reduce|reduce-scatter|all-to-all|"
-                     r"collective-permute)(?:-start|-done)?\(", s)
-        if not m:
-            continue
-        if "-done(" in s:
-            continue  # count async pairs once (at -start)
-        kind = m.group(2)
-        counts[kind] += 1
-        nbytes[kind] += _shape_bytes(m.group(1))
-    return CollectiveStats(counts=counts, bytes_by_kind=nbytes)
 
 
 @dataclasses.dataclass
@@ -119,31 +64,6 @@ class Roofline:
             "collective_s": self.collective_s,
             "bottleneck": self.bottleneck,
         }
-
-
-def from_compiled(compiled, mesh_devices: int) -> Tuple[Roofline,
-                                                        CollectiveStats]:
-    """Primary terms from the loop-aware analyzer (hlo_cost); XLA's own
-    cost_analysis (which counts while bodies once) is kept for reference
-    in the dry-run JSON."""
-    from . import hlo_cost
-    cost = hlo_cost.analyze(compiled.as_text())
-    colls = CollectiveStats(
-        counts={k: int(v) for k, v in cost.coll_counts.items()},
-        bytes_by_kind={k: int(v)
-                       for k, v in cost.coll_bytes_by_kind.items()})
-    rl = Roofline(flops=cost.flops, hbm_bytes=cost.bytes,
-                  collective_bytes=cost.coll_bytes,
-                  chips=mesh_devices, per_device=True)
-    return rl, colls
-
-
-def xla_cost_reference(compiled) -> Dict[str, float]:
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):
-        ca = ca[0]
-    return {"flops": float(ca.get("flops", 0.0)),
-            "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
 
 
 def model_flops(n_params: int, tokens: int, active_params: int = 0,

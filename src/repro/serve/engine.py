@@ -50,20 +50,43 @@ class Engine:
     def generate(self, prompts: np.ndarray,
                  frames: Optional[np.ndarray] = None) -> np.ndarray:
         """prompts [B, S_prompt] int32 -> [B, max_new_tokens]."""
+        out = np.zeros((prompts.shape[0], self.scfg.max_new_tokens),
+                       np.int32)
+        for i, (tok, _) in enumerate(self._steps(prompts, frames)):
+            out[:, i] = tok
+        return out
+
+    def generate_with_logits(self, prompts: np.ndarray,
+                             frames: Optional[np.ndarray] = None):
+        """Like ``generate``, and also returns the logits each emitted
+        token was sampled from: [B, n_emitted, padded_vocab], in the
+        compute dtype (n_emitted < max_new_tokens only on an early stop
+        when every sequence hit ``eos_id``). Holds every step's logits on
+        the device, O(B * n_emitted * padded_vocab): a checking path,
+        not the serving one."""
+        out = np.zeros((prompts.shape[0], self.scfg.max_new_tokens),
+                       np.int32)
+        steps = []
+        for i, (tok, logits) in enumerate(self._steps(prompts, frames)):
+            out[:, i] = tok
+            steps.append(logits)
+        return out, jnp.stack(steps, axis=1)
+
+    def _steps(self, prompts: np.ndarray, frames: Optional[np.ndarray]):
+        """Yields (emitted tokens [B] as numpy, the logits they were
+        sampled from) once per emitted step; finished sequences emit
+        ``eos_id``."""
         scfg = self.scfg
-        b = prompts.shape[0]
         batch = {"tokens": jnp.asarray(prompts)}
         if self.cfg.family == "audio":
             batch["frames"] = jnp.asarray(frames)
         logits, cache = self._prefill(self.params, batch)
 
         rng = jax.random.PRNGKey(scfg.seed)
-        out = np.zeros((b, scfg.max_new_tokens), np.int32)
-        done = np.zeros((b,), bool)
+        done = np.zeros((prompts.shape[0],), bool)
         tok = self._sample(logits, rng)
-        for i in range(scfg.max_new_tokens):
-            out[:, i] = np.where(done, scfg.eos_id or 0,
-                                 np.asarray(tok))
+        for _ in range(scfg.max_new_tokens):
+            yield np.where(done, scfg.eos_id or 0, np.asarray(tok)), logits
             if scfg.eos_id is not None:
                 done |= np.asarray(tok) == scfg.eos_id
                 if done.all():
@@ -71,7 +94,6 @@ class Engine:
             logits, cache = self._decode(self.params, cache, tok)
             rng, sub = jax.random.split(rng)
             tok = self._sample(logits, sub)
-        return out
 
     def _sample(self, logits, rng):
         if self.scfg.temperature <= 0.0:

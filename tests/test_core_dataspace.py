@@ -5,10 +5,7 @@ import random
 import numpy as np
 import pytest
 
-try:  # property tests prefer hypothesis; fall back to fixed seeded draws
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _prop_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (LayerSpec, dram_pim, generate_analytical,
                         generate_exhaustive, heuristic_mapping,

@@ -5,10 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:  # property tests prefer hypothesis; fall back to fixed seeded draws
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _prop_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 pytestmark = pytest.mark.kernels  # JAX/Pallas compile-heavy (see pytest.ini)
 

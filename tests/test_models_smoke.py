@@ -100,3 +100,76 @@ def test_moe_capacity_drops_consistently():
     ye, _ = moe_einsum(cfg, params, x)
     np.testing.assert_allclose(np.asarray(yg), np.asarray(ye),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_serve_launcher_engine_matches_uncached_forward():
+    """launch/serve.py's path on the smoke config: --smoke is opt-in, and
+    the engine's cached-decode logits match the uncached forward on the
+    same tokens, teacher-forced (the check chip_smoke.py makes at full
+    width, with the same bf16 bound)."""
+    from repro.launch import serve as serve_launch
+    assert not serve_launch.parse_args([]).smoke
+    args = serve_launch.parse_args(["--smoke", "--prompt-len", "16",
+                                    "--new-tokens", "8"])
+    eng = serve_launch.build_engine(args)
+    cfg = eng.cfg
+    prompts = serve_launch.make_prompts(eng, args)
+    toks, logits = eng.generate_with_logits(prompts)
+    np.testing.assert_array_equal(toks, eng.generate(prompts))
+    assert logits.shape == (args.batch, args.new_tokens, cfg.padded_vocab)
+    assert serve_launch.check_against_forward(eng, prompts, toks,
+                                              logits)["ok"]
+
+
+def _cache_one_behind(cache, prompt_len):
+    return {**cache, "pos": cache["pos"] - 1}
+
+
+def _cache_slot_zeroed(cache, prompt_len):
+    return {**cache, "layers": jax.tree_util.tree_map(
+        lambda a: a.at[:, :, prompt_len // 2].set(0), cache["layers"])}
+
+
+@pytest.mark.parametrize("fault", [_cache_one_behind, _cache_slot_zeroed])
+def test_serve_logit_check_catches_cache_fault(fault):
+    """The engine-vs-forward check fails when the KV cache is broken
+    after prefill: decode writing one position behind (each new token
+    overwrites its predecessor's slot), or one prompt slot's k/v zeroed
+    in every layer."""
+    from repro.launch import serve as serve_launch
+    args = serve_launch.parse_args(["--smoke", "--prompt-len", "16",
+                                    "--new-tokens", "8"])
+    eng = serve_launch.build_engine(args)
+    prefill = eng._prefill
+
+    def broken_prefill(params, batch):
+        logits, cache = prefill(params, batch)
+        return logits, fault(cache, args.prompt_len)
+
+    eng._prefill = broken_prefill
+    prompts = serve_launch.make_prompts(eng, args)
+    toks, logits = eng.generate_with_logits(prompts)
+    got = serve_launch.check_against_forward(eng, prompts, toks, logits)
+    assert got["max_abs_dlogit"] > serve_launch.LOGIT_MAX_BOUND
+    assert got["mean_abs_dlogit"] > serve_launch.LOGIT_MEAN_BOUND
+    assert not got["ok"]
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """The env var wins and nothing is set in code; otherwise the cache
+    sits at the fixed <repo>/.jax_cache."""
+    import os
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    try:
+        assert compile_cache.enable_compile_cache() == os.path.join(
+            repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

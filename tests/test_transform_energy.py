@@ -11,10 +11,7 @@ model.
 import numpy as np
 import pytest
 
-try:  # property tests prefer hypothesis; fall back to fixed seeded draws
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _prop_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (SearchConfig, combine_objective, describe,
                         dram_pim, evaluate_chain, heuristic_mapping,

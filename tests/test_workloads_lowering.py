@@ -27,10 +27,7 @@ import random
 import numpy as np
 import pytest
 
-try:  # property tests prefer hypothesis; fall back to fixed seeded draws
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _prop_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import ARCH_IDS, get_config
 from repro.core import (FullMap, IdentityMap, SearchConfig, WeightMap,
