@@ -36,11 +36,12 @@ waiting requests. Every subcommand takes
 to a JSONL trace, the end-of-run metrics snapshot to a JSON file that
 ``obs-report`` renders as cache hit rates, latency percentiles and
 fleet/service counters (``--prometheus`` for scrape-format text).
-``obs-profile`` analyzes the span JSONL a ``--trace-out`` run wrote:
-self/total-time attribution per span name, the critical path, and
-optional Chrome trace-event JSON (``--chrome-out``, loadable in
-Perfetto / chrome://tracing) and folded-stack flamegraph text
-(``--folded-out``).
+``--profile-dir DIR`` also runs the subcommand under the JAX profiler,
+whose trace in DIR (Perfetto's format too) holds the spans beside the
+device operations, on one clock. ``obs-profile`` analyzes the span
+JSONL a ``--trace-out`` run wrote: self/total-time attribution per
+span name, the critical path, and optional folded-stack flamegraph
+text (``--folded-out``).
 """
 import argparse
 import dataclasses
@@ -72,6 +73,11 @@ def _obs_flags(p: argparse.ArgumentParser) -> argparse.ArgumentParser:
                    help="keep every Nth span per span name "
                         "(deterministic stride, never RNG; metrics "
                         "counters are always exact)")
+    g.add_argument("--profile-dir", default=None, metavar="DIR",
+                   help="run under the JAX profiler and write its trace "
+                        "(xplane and Perfetto JSON) to DIR, with the "
+                        "spans on the device trace's clock (enables "
+                        "telemetry; spans default to DIR/spans.jsonl)")
     return p
 
 
@@ -83,15 +89,27 @@ def _setup_obs(args):
     finalizer that writes the registry snapshot to ``--metrics-out``
     and turns telemetry back off (pass ``extra=...`` to merge
     additional top-level keys — e.g. the serve flight recorder — into
-    the saved snapshot). With no obs flags the finalizer is a no-op
-    and telemetry stays disabled."""
+    the saved snapshot). With ``--profile-dir`` the run is also wrapped
+    in a JAX profiler session, stopped by the finalizer. With no obs
+    flags the finalizer is a no-op and telemetry stays disabled."""
     import json
     trace_out = getattr(args, "trace_out", None)
     metrics_out = getattr(args, "metrics_out", None)
-    if not trace_out and not metrics_out:
+    profile_dir = getattr(args, "profile_dir", None)
+    if not trace_out and not metrics_out and not profile_dir:
         return lambda extra=None: None
     from repro import obs
     metrics_out = metrics_out or DEFAULT_METRICS_OUT
+    if profile_dir:
+        # spans reach the profiler's trace only from a live sink
+        trace_out = trace_out or os.path.join(profile_dir, "spans.jsonl")
+        os.makedirs(profile_dir, exist_ok=True)
+        import jax
+        opts = jax.profiler.ProfileOptions()
+        # annotations only: the Python tracer writes gigabytes a minute
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(profile_dir, create_perfetto_trace=True,
+                                 profiler_options=opts)
     obs.enable(trace_path=trace_out,
                sample_every=max(1, getattr(args, "obs_sample", 1)))
 
@@ -100,7 +118,9 @@ def _setup_obs(args):
         snap = reg.snapshot() if reg is not None else {}
         if extra:
             snap.update(extra)
-        obs.disable()          # flushes + closes the trace sink
+        if profile_dir:
+            jax.profiler.stop_trace()
+        obs.disable()          # writes out the trace sink
         d = os.path.dirname(metrics_out)
         if d:
             os.makedirs(d, exist_ok=True)
@@ -110,6 +130,8 @@ def _setup_obs(args):
         msg = f"obs: metrics -> {metrics_out}"
         if trace_out:
             msg += f" trace -> {trace_out}"
+        if profile_dir:
+            msg += f" profile -> {profile_dir}"
         print(msg)
 
     return finish
@@ -172,21 +194,18 @@ def obs_report_main(argv) -> None:
 
 def obs_profile_main(argv) -> None:
     """Analyze a span JSONL trace (``--trace-out``): per-span-name
-    self/total-time attribution, the critical path, and optional
-    Chrome trace-event / folded-flamegraph exports."""
+    self/total-time attribution, the critical path, and an optional
+    folded-flamegraph export."""
     from repro.obs import profile as obs_profile
 
     p = argparse.ArgumentParser(
         prog="run.py obs-profile",
         description="Trace analytics for a repro.obs span JSONL: "
                     "where did the run's wall clock go (self-time "
-                    "attribution, critical path), plus Chrome "
-                    "trace-event JSON (Perfetto / chrome://tracing) "
-                    "and folded-stack flamegraph exports.")
+                    "attribution, critical path), plus a folded-stack "
+                    "flamegraph export.")
     p.add_argument("--trace", required=True, metavar="PATH",
                    help="span JSONL written by --trace-out")
-    p.add_argument("--chrome-out", default=None, metavar="PATH",
-                   help="write Chrome trace-event JSON to PATH")
     p.add_argument("--folded-out", default=None, metavar="PATH",
                    help="write folded stacks ('a;b;c <us>' lines, "
                         "flamegraph.pl-compatible) to PATH")
@@ -200,10 +219,6 @@ def obs_profile_main(argv) -> None:
         sys.exit(2)
     trace = obs_profile.parse_trace(args.trace)
     sys.stdout.write(obs_profile.render_profile(trace, top=args.top))
-    if args.chrome_out:
-        obs_profile.write_chrome_trace(trace, args.chrome_out)
-        print(f"obs-profile: chrome trace -> {args.chrome_out} "
-              "(load in Perfetto or chrome://tracing)")
     if args.folded_out:
         obs_profile.write_folded(trace, args.folded_out)
         print(f"obs-profile: folded stacks -> {args.folded_out}")

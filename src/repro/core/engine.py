@@ -48,6 +48,7 @@ directly and shared across bundles.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -81,6 +82,10 @@ _STAT_KEYS = ("tiles_hit", "tiles_miss", "tail_hit", "tail_miss",
               "sepcls_hit", "sepcls_miss", "score_hit", "score_miss",
               "score_pool_hit", "batch_scored", "dense_scored",
               "guard_fallback", "evictions", "perf_hit", "perf_miss")
+# engine-local stage timers (plain float seconds in ``OverlapEngine.times``,
+# published beside ``stats`` as float counters): the batched class-histogram
+# scorer, and the dense per-candidate path with its ready-step pass
+_TIME_KEYS = ("score_batch_s", "score_dense_s")
 
 
 def _unique_inverse(codes: np.ndarray, bound: int):
@@ -188,7 +193,10 @@ class OverlapEngine:
         #: telemetry dispatch in the hot loops; ``publish_metrics``
         #: forwards deltas to ``repro.obs``)
         self.stats: Dict[str, int] = {k: 0 for k in _STAT_KEYS}
-        self._published: Dict[str, int] = {k: 0 for k in _STAT_KEYS}
+        #: stage seconds (kept apart from ``stats``, which stays integer)
+        self.times: Dict[str, float] = {k: 0.0 for k in _TIME_KEYS}
+        self._published: Dict[str, float] = {
+            k: 0 for k in _STAT_KEYS + _TIME_KEYS}
 
     def _arange(self, n: int) -> np.ndarray:
         a = self._ar.get(n)
@@ -241,8 +249,6 @@ class OverlapEngine:
             self._arch = None
         if bundle is not None:
             self.stats["evictions"] += 1
-            obs.event("engine.evict_arch", arch=key,
-                      remaining=len(self._bundles))
         return bundle is not None
 
     def evict_lru(self, keep: int) -> int:
@@ -260,11 +266,12 @@ class OverlapEngine:
         return n
 
     def publish_metrics(self, registry=None) -> None:
-        """Forward ``stats`` deltas since the last publish into the obs
-        registry as ``engine.*`` counters (plus the live bundle-count
-        gauge). Called at search boundaries — never from hot loops — so
-        the sustained scoring path performs zero telemetry dispatch.
-        No-op when telemetry is disabled and no ``registry`` is given."""
+        """Forward ``stats`` and ``times`` deltas since the last publish
+        into the obs registry as ``engine.*`` counters (plus the live
+        bundle-count gauge). Called at search boundaries — never from
+        hot loops — so the sustained scoring path performs zero
+        telemetry dispatch. No-op when telemetry is disabled and no
+        ``registry`` is given."""
         reg = registry if registry is not None else obs.registry()
         if reg is None:
             return
@@ -272,7 +279,7 @@ class OverlapEngine:
         # ``engine.perf_hit``/``perf_miss`` ride the same delta cursor
         self.stats["perf_hit"] = self._perf.hits
         self.stats["perf_miss"] = self._perf.misses
-        for k, v in self.stats.items():
+        for k, v in list(self.stats.items()) + list(self.times.items()):
             d = v - self._published[k]
             if d:
                 reg.counter("engine." + k).inc(d)
@@ -1061,10 +1068,19 @@ class OverlapEngine:
         fast = (bool(edges[i]) and mode in ("overlap", "transform")
                 and all(type(e.cmap) is IdentityMap for e in edges[i])
                 and len({e.cmap.key() for e in edges[i]}) == 1)
-        scored = (self._score_identity_batch(i, sub, edges, done, mode,
-                                             has_consumer, objective,
-                                             blend_alpha)
-                  if fast else [None] * len(sub))
+        if fast:
+            t0 = time.perf_counter()
+            scored = self._score_identity_batch(i, sub, edges, done, mode,
+                                                has_consumer, objective,
+                                                blend_alpha)
+            self.times["score_batch_s"] += time.perf_counter() - t0
+        else:
+            scored = [None] * len(sub)
+        # the dense stage: the generic ready-step pass and the
+        # per-candidate loop, timed once per call and counted only when
+        # some candidate of this call was scored densely
+        n_dense = self.stats["dense_scored"]
+        t0 = time.perf_counter()
         if edges[i] and not fast:
             for e in edges[i]:
                 self.ready_steps_batch(done[e.producer].mapping, sub,
@@ -1083,6 +1099,8 @@ class OverlapEngine:
             skey = (mode, objective, blend_alpha, m.cache_key,
                     has_consumer, pids)
             self._cur.score[skey] = (prods, sc)
+        if self.stats["dense_scored"] != n_dense:
+            self.times["score_dense_s"] += time.perf_counter() - t0
         self._cur.score[pkey] = (prods, out.copy())
         return out
 
@@ -1191,7 +1209,8 @@ def optimize_network_engine(layers: Sequence[LayerSpec],
         with obs.span("search.layer", layer=i, mode=cfg.mode,
                       strategy=cfg.strategy,
                       phase="backward" if i in backward_part else "forward"):
-            cands = candidates(layers[i], arch, cfg, salt=i)
+            with obs.span("search.candidates", layer=i):
+                cands = candidates(layers[i], arch, cfg, salt=i)
             if i in backward_part:
                 scores = np.array([eng.score_backward(i, m, edges, chosen,
                                                       cfg.mode,
@@ -1214,10 +1233,12 @@ def optimize_network_engine(layers: Sequence[LayerSpec],
             # np.argmin == first minimum == min(cands, key=...) tie-break
             chosen[i] = cands[int(np.argmin(scores))]
             if all(e.producer in done for e in edges[i]):
-                done[i] = eng.layer_result(i, chosen[i], edges, done,
-                                           cfg.mode)
+                with obs.span("search.commit", layer=i):
+                    done[i] = eng.layer_result(i, chosen[i], edges, done,
+                                               cfg.mode)
     cur_maps = [chosen[i] for i in range(n)]
-    result = eng.evaluate_chain(cur_maps, edges, cfg.mode)
+    with obs.span("search.commit"):
+        result = eng.evaluate_chain(cur_maps, edges, cfg.mode)
 
     # coordinate-descent refinement: trials differ from the current chain
     # in one layer, so only that layer + transitive consumers re-evaluate

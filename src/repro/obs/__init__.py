@@ -12,8 +12,8 @@ Section 12). Three parts:
 * :mod:`repro.obs.report` — ``render_report`` turns a snapshot into
   the ``run.py obs-report`` terminal summary.
 * :mod:`repro.obs.profile` — span-trace analytics (call tree, self/
-  total-time attribution, critical path, Chrome trace-event JSON and
-  folded-flamegraph export) behind ``run.py obs-profile``.
+  total-time attribution, critical path, folded-flamegraph export)
+  behind ``run.py obs-profile``.
 * :mod:`repro.obs.flight` — ``FlightRecorder``, the bounded ring of
   per-request serving records (stage timings, provenance, slow-request
   full-detail retention) behind ``GET /v1/debug/requests``.
@@ -38,8 +38,8 @@ from .flight import FlightRecorder
 from .metrics import (Counter, Gauge, Histogram, Registry,
                       escape_label_value, merge_snapshots, quantile,
                       render_prometheus)
-from .profile import (Trace, attribution, chrome_trace, critical_path,
-                      folded_stacks, parse_trace, render_profile)
+from .profile import (Trace, attribution, critical_path, folded_stacks,
+                      parse_trace, render_profile)
 from .report import render_report
 from .trace import (NullTelemetry, Telemetry, TraceSink, current, disable,
                     enable, enabled, event, inc, observe, registry,
@@ -51,7 +51,7 @@ __all__ = [
     "escape_label_value", "merge_snapshots", "quantile",
     "render_prometheus",
     "render_report",
-    "Trace", "attribution", "chrome_trace", "critical_path",
+    "Trace", "attribution", "critical_path",
     "folded_stacks", "parse_trace", "render_profile",
     "FlightRecorder", "SLOTracker", "WindowHistogram",
     "NullTelemetry", "Telemetry", "TraceSink",

@@ -4,9 +4,10 @@ Aggregate counters say *that* serving latency moved; the flight
 recorder says *where a given request's milliseconds went*. Every
 request through ``MappingService`` leaves one compact record — stage
 timings (admit-wait / evaluate / respond, threaded through the staged
-``JobQueue``), ``served_from`` provenance, work counters, outcome —
-in a fixed-capacity ring buffer (``collections.deque``), so memory is
-bounded no matter how long the server runs.
+``JobQueue``; ``lock_wait_s``, the part of evaluate spent waiting for
+the shared engine's lock), ``served_from`` provenance, work counters,
+outcome — in a fixed-capacity ring buffer (``collections.deque``), so
+memory is bounded no matter how long the server runs.
 
 Slow-request retention: records whose ``total_s`` meets
 ``slow_threshold_s`` keep their **full detail** (the request dict, the
@@ -32,8 +33,9 @@ from typing import Dict, List, Optional
 #: record fields every entry carries (detail fields ride on top)
 CORE_FIELDS = ("key", "seq", "t_wall", "network", "family", "objective",
                "served_from", "outcome", "status", "admit_wait_s",
-               "evaluate_s", "respond_s", "total_s", "evaluated",
-               "from_journal", "proposed", "deadline_hit", "slow")
+               "evaluate_s", "respond_s", "total_s", "lock_wait_s",
+               "evaluated", "from_journal", "proposed", "deadline_hit",
+               "slow")
 
 
 class FlightRecorder:

@@ -14,13 +14,13 @@ the artifacts a latency investigation actually needs:
 * the **critical path** — from the longest root span, repeatedly
   descend into the longest child — the single chain a perf fix must
   shorten to move the end-to-end number;
-* **Chrome trace-event JSON** (``ph: "X"`` complete events) loadable
-  in Perfetto / ``chrome://tracing``;
 * **folded-stack text** (``root;child;leaf <self_us>`` lines), the
   input format of the standard flamegraph toolchain.
 
 All surfaced as ``benchmarks/run.py obs-profile --trace <file>
-[--chrome-out P] [--folded-out P] [--top N]``.
+[--folded-out P] [--top N]``. For a timeline, run the traced command
+with ``--profile-dir``: the spans then sit beside the device operations
+in the profiler's own trace, which Perfetto opens.
 
 Trace-format tolerance: spans written before the start-timestamp fix
 carry only the end wall clock (``ts``) — starts fall back to
@@ -198,27 +198,6 @@ def critical_path(trace: Trace) -> List[Dict]:
     return path
 
 
-def chrome_trace(trace: Trace) -> Dict:
-    """Chrome trace-event JSON (the ``chrome://tracing`` / Perfetto
-    load format): one ``ph: "X"`` complete event per span, timestamps
-    in microseconds relative to the earliest span start, thread ids
-    preserved, span attributes in ``args``."""
-    events: List[Dict] = []
-    t_base = min((n.ts0 for n in trace.walk()), default=0.0)
-    for node in trace.walk():
-        events.append({
-            "name": node.name,
-            "ph": "X",
-            "ts": round((node.ts0 - t_base) * 1e6, 3),
-            "dur": round(node.dur_s * 1e6, 3),
-            "pid": 1,
-            "tid": node.tid,
-            "args": node.attrs,
-        })
-    events.sort(key=lambda e: (e["tid"], e["ts"]))
-    return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-
 def folded_stacks(trace: Trace) -> List[str]:
     """Folded-stack lines (``a;b;c <self_us>``) — the collapsed input
     of the standard flamegraph toolchain; zero-self frames are kept
@@ -272,13 +251,6 @@ def render_profile(trace: Trace, top: int = 15) -> str:
                      f"{step['dur_s'] * 1e3:.3f}ms "
                      f"(self {step['self_s'] * 1e3:.3f}ms)")
     return "\n".join(lines) + "\n"
-
-
-def write_chrome_trace(trace: Trace, path: str) -> None:
-    """Write the Chrome trace-event JSON to ``path``."""
-    with open(path, "w", encoding="utf-8") as fh:
-        json.dump(chrome_trace(trace), fh, sort_keys=True)
-        fh.write("\n")
 
 
 def write_folded(trace: Trace, path: str) -> None:

@@ -6,11 +6,16 @@ no-op singletons, so the disabled cost of an instrumented call site is
 one dict/attribute lookup and a truthiness test. ``enable()`` swaps in
 a live ``Telemetry`` (optionally with a JSONL ``TraceSink`` and a
 ``sample_every`` span-sampling stride); ``disable()`` restores the
-null default and closes the sink.
+null default and writes out the sink.
 
-Spans nest: each ``with obs.span("dse.sweep", budget=8):`` writes one
+Spans nest: each ``with obs.span("dse.sweep", budget=8):`` records one
 JSONL line at exit with the span name, wall-clock duration, nesting
-depth (tracked per-thread) and any keyword attributes. Sampling is
+depth (tracked per-thread) and any keyword attributes. The sink keeps
+lines in memory and writes them at ``close()`` (``disable()``), or
+whenever ``TraceSink.FLUSH_LINES`` have piled up. Where JAX is already
+imported, a live span also enters ``jax.profiler.TraceAnnotation``, so
+an active profiler session shows it on the device trace's clock; this
+module never imports JAX itself. Sampling is
 *counter-based* (every Nth span of a given name), never RNG-based, so
 tracing can never perturb the deterministic search results —
 the DESIGN.md Section 12 contract.
@@ -23,42 +28,57 @@ runtime.
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 from .metrics import Registry
 
 
 class TraceSink:
-    """Append-only JSONL event writer (lazily opened, line-flushed)."""
+    """Append-only JSONL event writer that buffers in memory.
+
+    ``write`` serializes an event and appends the line to a list under
+    the sink's lock; ``close`` writes the list out (and so does reaching
+    ``FLUSH_LINES`` lines, which bounds a long run's memory). The file
+    opens in append mode at each flush, so writes after a ``close``
+    land after the earlier lines. Whole lines are appended under one
+    lock, so concurrent writers never tear a line."""
+
+    #: buffered lines that trigger a write before ``close``
+    FLUSH_LINES = 4096
 
     def __init__(self, path: str):
         self.path = path
-        self._fh = None
+        self._lines: List[str] = []
         self._lock = threading.Lock()
 
     def write(self, ev: Dict) -> None:
-        """Serialize one event dict as a JSON line and flush it."""
+        """Serialize one event dict as a JSON line and buffer it."""
         line = json.dumps(ev, sort_keys=True)
         with self._lock:
-            if self._fh is None:
-                self._fh = open(self.path, "a", encoding="utf-8")
-            self._fh.write(line + "\n")
-            self._fh.flush()
+            self._lines.append(line)
+            if len(self._lines) >= self.FLUSH_LINES:
+                self._flush()
+
+    def _flush(self) -> None:
+        if self._lines:
+            with open(self.path, "a", encoding="utf-8") as fh:
+                fh.write("\n".join(self._lines) + "\n")
+            self._lines = []
 
     def close(self) -> None:
-        """Close the underlying file (later writes reopen it)."""
+        """Write every buffered line (later writes buffer anew)."""
         with self._lock:
-            if self._fh is not None:
-                self._fh.close()
-                self._fh = None
+            self._flush()
 
 
 class _Span:
-    """Context manager timing one named span; writes JSONL on exit."""
+    """Context manager timing one named span; records JSONL on exit
+    and, where JAX is loaded, marks the profiler trace too."""
 
-    __slots__ = ("_tel", "_name", "_attrs", "_t0", "_wall0")
+    __slots__ = ("_tel", "_name", "_attrs", "_t0", "_wall0", "_ann")
 
     def __init__(self, tel: "Telemetry", name: str, attrs: Dict):
         self._tel = tel
@@ -67,12 +87,19 @@ class _Span:
 
     def __enter__(self) -> "_Span":
         self._tel._depth().append(self._name)
+        prof = sys.modules.get("jax.profiler")
+        self._ann = None
+        if prof is not None:
+            self._ann = prof.TraceAnnotation(self._name)
+            self._ann.__enter__()
         self._wall0 = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         stack = self._tel._depth()
         stack.pop()
         self._tel._emit_span(self._name, dur, len(stack), self._attrs,
@@ -137,7 +164,7 @@ class Telemetry:
         # ``ts`` (end) and ``ts0`` (start) share one wall-clock base, so
         # trace analytics never reconstruct starts by mixing the
         # ``time.time`` and ``perf_counter`` bases; ``tid`` keys the
-        # per-thread span streams for call-tree/Chrome-trace export.
+        # per-thread span streams for call-tree export.
         # Older traces lack ``ts0``/``tid`` — ``repro.obs.profile``
         # falls back to ``ts - dur_s`` and a single implicit thread.
         end = time.time()
@@ -207,7 +234,7 @@ def enable(trace_path: Optional[str] = None, sample_every: int = 1,
 
 
 def disable() -> None:
-    """Restore the no-op default and close any open trace sink."""
+    """Restore the no-op default and write out the trace sink."""
     global _current
     sink = getattr(_current, "sink", None)
     _current = _NULL

@@ -499,6 +499,7 @@ class MappingService:
                     served_from: str, outcome: str, status: str,
                     admit_wait_s: float = 0.0, evaluate_s: float = 0.0,
                     respond_s: float = 0.0, total_s: float = 0.0,
+                    lock_wait_s: float = 0.0,
                     resp: Optional[MappingResponse] = None) -> Dict:
         """One compact flight record (``obs.flight.CORE_FIELDS``)."""
         rec = {"key": key, "network": req.network, "family": req.family,
@@ -506,7 +507,7 @@ class MappingService:
                "outcome": outcome, "status": status,
                "admit_wait_s": admit_wait_s, "evaluate_s": evaluate_s,
                "respond_s": respond_s, "total_s": total_s,
-               "evaluated": 0, "from_journal": 0, "proposed": 0,
+               "lock_wait_s": lock_wait_s, "evaluated": 0, "from_journal": 0, "proposed": 0,
                "deadline_hit": False}
         if resp is not None:
             rec.update(evaluated=resp.evaluated,
@@ -546,7 +547,8 @@ class MappingService:
             outcome="ok" if err is None else "error",
             status=resp.status if resp is not None else "error",
             admit_wait_s=admit, evaluate_s=evaluate, respond_s=respond,
-            total_s=total, resp=resp)
+            total_s=total, lock_wait_s=extra.get("lock_wait_s", 0.0),
+            resp=resp)
         detail: Dict[str, Any] = {"request": req.to_dict()}
         if err is not None:
             detail["error"] = err
@@ -577,8 +579,13 @@ class MappingService:
                 # the shared engine retains this family's arch bundles
                 # (and the content-keyed PerfCache), so the next
                 # same-family request starts warm; the LRU cap keeps a
-                # many-tenant server's memory bounded
+                # many-tenant server's memory bounded. The wait for
+                # the lock is part of ``evaluate_s`` and is recorded
+                # on its own as ``lock_wait_s``
+                t_wait = time.perf_counter()
                 with self._engine_lock:
+                    if extra is not None:
+                        extra["lock_wait_s"] = time.perf_counter() - t_wait
                     before = dict(self._engine.stats)
                     res = execute_sweep(
                         cfg, space=self._space(req.family),

@@ -20,9 +20,9 @@ import json
 import pytest
 
 from repro import obs
-from repro.core import (LayerSpec, SearchConfig, chain_edges, dram_pim,
-                        optimize_network)
-from repro.core.engine import OverlapEngine
+from repro.core import (Edge, FullMap, LayerSpec, SearchConfig,
+                        chain_edges, dram_pim, optimize_network)
+from repro.core.engine import OverlapEngine, optimize_network_engine
 from repro.core.search import _consumers_of, candidates
 from repro.dse import (DSEConfig, DistribConfig, ParamSpace,
                        run_distributed, run_dse)
@@ -289,6 +289,26 @@ def test_metrics_without_sink():
     assert "span.s" not in snap["histograms"]
 
 
+def test_trace_sink_buffers_until_close_or_bound(tmp_path):
+    """Spans stay in memory: nothing reaches the file before ``close``
+    or before ``FLUSH_LINES`` lines have piled up, and then all of
+    them do, in order."""
+    import os
+
+    path = str(tmp_path / "t.jsonl")
+    sink = TraceSink(path)
+    sink.FLUSH_LINES = 4
+    for i in range(3):
+        sink.write({"i": i})
+    assert not os.path.exists(path)
+    sink.write({"i": 3})                      # the bound: all four out
+    assert [e["i"] for e in _read_events(path)] == [0, 1, 2, 3]
+    sink.write({"i": 4})
+    assert len(_read_events(path)) == 4
+    sink.close()
+    assert [e["i"] for e in _read_events(path)] == [0, 1, 2, 3, 4]
+
+
 def test_trace_sink_reopens_after_close(tmp_path):
     path = str(tmp_path / "t.jsonl")
     sink = TraceSink(path)
@@ -452,6 +472,115 @@ def test_publish_metrics_publishes_deltas_once():
     assert reg.snapshot()["counters"] == first
     # with telemetry disabled and no explicit registry: a silent no-op
     eng.publish_metrics()
+
+
+def _mixed_net():
+    """An identity edge (l0 -> l1, the batched scorer) and a FullMap
+    edge (l1 -> l2, the dense fallback)."""
+    layers = _conv_chain()
+    edges = [[], [Edge(0)], [Edge(1, FullMap())]]
+    return layers, edges
+
+
+def test_publish_metrics_times_follow_their_counts():
+    """``engine.score_batch_s``/``score_dense_s`` are published once
+    per delta, and each is positive exactly when ``batch_scored``/
+    ``dense_scored`` grew since the last publish."""
+    layers, edges = _mixed_net()
+    arch = _small_arch()
+    cfg = SearchConfig(n_candidates=8, seed=0, max_steps=512,
+                       mode="transform")
+    res = optimize_network(layers, edges, arch, cfg)
+    done = {i: lr for i, lr in enumerate(res.layers)}
+    eng = OverlapEngine()
+    reg = Registry()
+
+    def score_and_publish(i):
+        before = reg.snapshot()["counters"]
+        eng.score_forward_batch(i, candidates(layers[i], arch, cfg, salt=i),
+                                edges, done, "transform", i < 2)
+        eng.publish_metrics(registry=reg)
+        after = reg.snapshot()["counters"]
+        return {k: after.get(k, 0) - before.get(k, 0) for k in after}
+
+    for i, batch, dense in ((1, True, False), (2, False, True)):
+        d = score_and_publish(i)
+        assert (d.get("engine.batch_scored", 0) > 0) is batch
+        assert (d.get("engine.score_batch_s", 0) > 0) is batch
+        assert (d.get("engine.dense_scored", 0) > 0) is dense
+        assert (d.get("engine.score_dense_s", 0) > 0) is dense
+    assert eng.times["score_batch_s"] > 0 and eng.times["score_dense_s"] > 0
+    # the times stay out of the integer stats the service diffs
+    assert all(isinstance(v, int) for v in eng.stats.values())
+    first = reg.snapshot()["counters"]
+    eng.publish_metrics(registry=reg)
+    assert reg.snapshot()["counters"] == first
+
+
+def _traced_search(tmp_path):
+    """One traced engine search of the mixed net: (spans by name as
+    JSONL events, the published counters)."""
+    layers, edges = _mixed_net()
+    cfg = SearchConfig(n_candidates=8, seed=0, max_steps=512,
+                       mode="transform")
+    trace = str(tmp_path / "t.jsonl")
+    obs.enable(trace_path=trace)
+    optimize_network_engine(layers, edges, _small_arch(), cfg)
+    counters = obs.registry().snapshot()["counters"]
+    obs.disable()
+    spans = {}
+    for ev in _read_events(trace):
+        spans.setdefault(ev["name"], []).append(ev)
+    return spans, counters
+
+
+def test_search_stage_spans_and_timers_fit_inside_the_layers(tmp_path):
+    """One ``search.candidates`` and one ``search.commit`` per layer,
+    plus the closing ``search.commit``; the stages and the engine's
+    scoring timers together take no more than the layers and the
+    closing commit."""
+    spans, counters = _traced_search(tmp_path)
+    n = len(_mixed_net()[0])
+    assert len(spans["search.layer"]) == n
+    assert len(spans["search.candidates"]) == n
+    commits = spans["search.commit"]
+    assert sorted(e.get("layer", -1) for e in commits) \
+        == [-1] + list(range(n))
+    closing = sum(e["dur_s"] for e in commits if "layer" not in e)
+    stages = (sum(e["dur_s"] for e in spans["search.candidates"])
+              + sum(e["dur_s"] for e in commits)
+              + counters["engine.score_batch_s"]
+              + counters["engine.score_dense_s"])
+    assert 0 < stages <= sum(e["dur_s"] for e in spans["search.layer"]) \
+        + closing
+
+
+def test_profiler_host_plane_holds_each_search_span(tmp_path):
+    """With JAX loaded, every span is also a ``TraceAnnotation``: the
+    profiler's ``/host:`` plane holds one event per JSONL span."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "prof"),
+                             profiler_options=opts)
+    try:
+        spans, _ = _traced_search(tmp_path)
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "prof" / "**" / "*.xplane.pb"),
+                       recursive=True)
+    host = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    host[ev.name] = host.get(ev.name, 0) + 1
+    for name in ("search.layer", "search.candidates", "search.commit"):
+        assert host.get(name) == len(spans[name]), name
 
 
 def test_sustained_scoring_makes_zero_obs_dispatches(monkeypatch):

@@ -3,10 +3,11 @@
 Covers the parser's call-tree reconstruction (exit-order + per-thread
 depth adoption, sampled-out parents, old-format traces without
 ``ts0``/``tid``), the attribution invariant (self times sum to the
-root total), the Chrome trace-event and folded-stack exports, and the
-graceful handling of empty/truncated/missing trace files the CLI
-relies on.
+root total), the folded-stack export, the ``--profile-dir`` timeline,
+and the graceful handling of empty/truncated/missing trace files the
+CLI relies on.
 """
+import gzip
 import json
 import subprocess
 import sys
@@ -152,26 +153,6 @@ def test_critical_path_descends_longest_child(tmp_path):
     assert [s["name"] for s in steps] == ["root", "long", "long.leaf"]
 
 
-def test_chrome_trace_export_shape(tmp_path):
-    path = str(tmp_path / "t.jsonl")
-    _write_trace(path, [
-        _span("leaf", 100.5, 0.25, 1, tid=7, net="resnet18"),
-        _span("root", 100.0, 1.0, 0, tid=7),
-    ])
-    doc = pr.chrome_trace(pr.parse_trace(path))
-    assert doc["displayTimeUnit"] == "ms"
-    evs = doc["traceEvents"]
-    assert len(evs) == 2
-    for e in evs:
-        assert e["ph"] == "X" and e["pid"] == 1 and e["tid"] == 7
-        assert e["ts"] >= 0 and e["dur"] > 0
-    leaf = next(e for e in evs if e["name"] == "leaf")
-    assert leaf["ts"] == pytest.approx(0.5e6)       # µs after root start
-    assert leaf["dur"] == pytest.approx(0.25e6)
-    assert leaf["args"] == {"net": "resnet18"}
-    json.dumps(doc)                                  # valid JSON
-
-
 def test_folded_stacks_cover_every_microsecond(tmp_path):
     path = str(tmp_path / "t.jsonl")
     _write_trace(path, [
@@ -210,15 +191,31 @@ def test_obs_profile_cli_end_to_end(tmp_path):
         _span("leaf", 0.0, 0.4, 1),
         _span("root", 0.0, 1.0, 0),
     ])
-    chrome = str(tmp_path / "chrome.json")
     folded = str(tmp_path / "folded.txt")
-    r = _run_cli(["obs-profile", "--trace", trace, "--chrome-out",
-                  chrome, "--folded-out", folded], repo)
+    r = _run_cli(["obs-profile", "--trace", trace, "--folded-out",
+                  folded], repo)
     assert r.returncode == 0, r.stderr
     assert "critical path:" in r.stdout
-    doc = json.load(open(chrome, encoding="utf-8"))
-    assert len(doc["traceEvents"]) == 2
     assert open(folded, encoding="utf-8").read().strip()
+    # --profile-dir: the Perfetto trace holds each obs span once, on the
+    # profiler's timeline
+    prof = tmp_path / "prof"
+    r = _run_cli(["serve-dse", "--network", "olmo_1b_smoke:decode@16",
+                  "--explorer", "grid", "--budget", "1", "--candidates",
+                  "2", "--max-steps", "256",
+                  "--journal", str(tmp_path / "j.jsonl"),
+                  "--metrics-out", str(tmp_path / "m.json"),
+                  "--profile-dir", str(prof)], repo)
+    assert r.returncode == 0, r.stderr
+    [perfetto] = prof.glob("plugins/profile/*/perfetto_trace.json.gz")
+    with gzip.open(perfetto, "rt", encoding="utf-8") as fh:
+        names = [e.get("name") for e in json.load(fh)["traceEvents"]]
+    spans = [json.loads(line)["name"]
+             for line in open(prof / "spans.jsonl", encoding="utf-8")]
+    assert {"serve.request", "dse.sweep", "search.layer",
+            "search.commit"} <= set(spans)
+    for name in set(spans):
+        assert names.count(name) == spans.count(name), name
 
 
 def test_obs_profile_cli_missing_and_truncated(tmp_path):

@@ -657,6 +657,30 @@ def test_flight_records_every_request_path():
     json.dumps(recs)                               # JSON-safe
 
 
+def test_flight_records_engine_lock_wait():
+    """A request whose sweep queues behind a held engine lock records
+    the wait as ``lock_wait_s``, inside its ``evaluate_s``; memo
+    replays record none."""
+    svc = make_service()
+    held = 0.2
+    try:
+        with svc._engine_lock:
+            job = svc.submit(tiny_request())
+            deadline = time.monotonic() + 60
+            while job.t_eval_start is None and time.monotonic() < deadline:
+                time.sleep(0.001)
+            assert job.t_eval_start is not None
+            time.sleep(held)
+        job.result(120)
+        svc.request(tiny_request())                # -> memo
+    finally:
+        svc.close()
+    by_src = {r["served_from"]: r for r in svc.flight.snapshot()}
+    search = by_src["search"]
+    assert held * 0.5 < search["lock_wait_s"] <= search["evaluate_s"]
+    assert by_src["memo"]["lock_wait_s"] == 0.0
+
+
 def test_flight_stage_sum_matches_request_seconds_histogram():
     """Acceptance: a fresh request's admit_wait + evaluate equals the
     serve.request_seconds observation for it, up to the respond-stage
