@@ -21,7 +21,9 @@ changing a single produced number (DESIGN.md Section 6):
    maximum is separable across dims) and gathers back. ``IdentityMap``
    edges use the stronger separable path (``_ready_steps_identity``):
    tile corners factor into bank + step parts, so the scan touches only
-   distinct (bank value, step pair) combos.
+   distinct (bank value, step pair) combos. ``FullMap`` edges need no
+   consumer tiles at all (``_ready_steps_full``): every tile projects onto
+   the producer's whole output, so the step is one producer-only integer.
 3. **Radix transform ordering** — single-edge ready matrices are ordered
    by producer finish-time rank, handing ``transform_schedule`` a
    precomputed stable integer argsort instead of a float mergesort.
@@ -58,9 +60,10 @@ from .arch import ArchSpec
 from .dataspace import (rect_bounds, rect_bounds_separable,
                         rect_bounds_stacked)
 from .mapping import Mapping
-from .overlap import (Edge, IdentityMap, CoordMap, digit_scan,
-                      overlapped_end, rect_loop_groups, schedule_with_ready,
-                      stream_tail_fraction, stream_tail_fractions)
+from .overlap import (Edge, FullMap, IdentityMap, CoordMap, digit_scan,
+                      max_step_in_rect, overlapped_end, rect_loop_groups,
+                      schedule_with_ready, stream_tail_fraction,
+                      stream_tail_fractions)
 from .perf_model import LayerPerf, PerfCache
 from .search import (LayerResult, NetworkResult, SearchConfig,
                      _consumers_of, _visit_order, candidates,
@@ -79,6 +82,7 @@ _GRID_GUARD = 1 << 19
 # to the obs registry at search boundaries)
 _STAT_KEYS = ("tiles_hit", "tiles_miss", "tail_hit", "tail_miss",
               "proj_hit", "proj_miss", "ready_hit", "ready_miss",
+              "ready_full",
               "sepcls_hit", "sepcls_miss", "score_hit", "score_miss",
               "score_pool_hit", "batch_scored", "dense_scored",
               "guard_fallback", "evictions", "perf_hit", "perf_miss")
@@ -133,7 +137,7 @@ class _ArchCaches:
     unique per arch, so every per-mapping cache lives in a bundle)."""
 
     __slots__ = ("tiles", "tsep", "tail", "proj", "sepproj", "ready",
-                 "ranks", "score", "sepcls", "clsr0")
+                 "full", "ranks", "score", "sepcls", "clsr0")
 
     def __init__(self):
         self.tiles: Dict = {}    # mapping key -> (lo, hi) rect dicts
@@ -142,6 +146,7 @@ class _ArchCaches:
         self.proj: Dict = {}     # (consumer key, cmap key, producer layer)
         self.sepproj: Dict = {}  # same key -> separable combo decomposition
         self.ready: Dict = {}    # (producer key, consumer key, cmap key)
+        self.full: Dict = {}     # producer key -> FullMap ready step
         self.ranks: Dict = {}    # id(LayerResult) -> finish-step ranks
         self.score: Dict = {}    # scoring-context key -> pinned score
         self.sepcls: Dict = {}   # (consumer key, cmap key) -> _SepClasses
@@ -388,6 +393,8 @@ class OverlapEngine:
             self.stats["ready_miss"] += 1
             if type(cmap) is IdentityMap:
                 hit = self._ready_steps_identity(m_p, m_c, cmap)
+            elif type(cmap) is FullMap:
+                hit = self._ready_steps_full(m_p, m_c)
             else:
                 plo, phi, ready0 = self.projection(m_c, cmap, m_p.layer)
                 hit = (max_step_in_rect_dedup(m_p, plo, phi), ready0)
@@ -485,6 +492,27 @@ class OverlapEngine:
             best = digit_scan(loops, lo_c, hi_c)
             total = total + best[inv_b[:, None], inv_t[None, :]]
         return total.astype(np.int64), ready0
+
+    def _ready_steps_full(self, m_p: Mapping, m_c: Mapping):
+        """Closed form for ``FullMap`` edges: every consumer tile projects
+        onto the producer's whole output, which the clip leaves at
+        ``[0, dim)`` per output dim, so the ready step is the digit scan of
+        that one rectangle — a producer-only integer, cached per producer
+        mapping. ``ready0`` is all False. Both are read-only broadcast
+        views over the consumer's (n_banks, n_steps) grid; bit-identical
+        to ``ready_steps_analytical``, which scans the same rectangle once
+        per tile."""
+        self.stats["ready_full"] += 1
+        step = self._cur.full.get(m_p.cache_key)
+        if step is None:
+            pl = m_p.layer
+            zero = np.zeros(1, dtype=np.int64)
+            step = self._cur.full[m_p.cache_key] = max_step_in_rect(
+                m_p, {d: zero for d in OUTPUT_DIMS},
+                {d: np.full(1, pl.dim(d), dtype=np.int64)
+                 for d in OUTPUT_DIMS})[0]
+        shp = (m_c.n_banks, m_c.n_steps)
+        return np.broadcast_to(step, shp), np.broadcast_to(False, shp)
 
     # -- batched identity-edge scoring (class histograms) --------------------
 
@@ -844,10 +872,11 @@ class OverlapEngine:
         digit-scanned once. Results (bit-identical to the per-candidate
         scan) land in the ready cache and are returned per candidate.
         ``IdentityMap`` edges use the stronger separable per-candidate path
-        instead (deduplication beats concatenation there)."""
+        instead (deduplication beats concatenation there), ``FullMap``
+        edges the producer-only closed form (no consumer tiles at all)."""
         self._check_arch(m_p)
         cmap = cmap or IdentityMap()
-        if type(cmap) is IdentityMap:
+        if type(cmap) in (IdentityMap, FullMap):
             return [self.ready_steps(m_p, m, cmap) for m in cands]
         ck = cmap.key()
         pk = m_p.cache_key

@@ -11,10 +11,11 @@ import random
 import numpy as np
 import pytest
 
-from repro.core import (Edge, IdentityMap, LayerSpec, SearchConfig,
-                        chain_edges, describe, dram_pim, evaluate_chain,
-                        max_step_in_rect, optimize_network, random_mapping,
-                        ready_steps_analytical)
+from repro.core import (Edge, FullMap, IdentityMap, LayerSpec,
+                        SearchConfig, chain_edges, describe, dram_pim,
+                        evaluate_chain, max_step_in_rect, optimize_network,
+                        random_mapping, ready_steps_analytical,
+                        ready_steps_exhaustive)
 from repro.core.engine import (OverlapEngine, max_step_in_rect_dedup,
                                optimize_network_engine)
 from repro.core.search import (_consumers_of, _optimize_network_reference,
@@ -110,6 +111,39 @@ def test_engine_ready_steps_batch_matches_single():
                 sa, ra = ready_steps_analytical(prod, m, e.cmap)
                 assert np.array_equal(sa, se)
                 assert np.array_equal(ra, re)
+
+
+@pytest.mark.parametrize("shape", ["conv", "matmul"])
+@pytest.mark.parametrize("seed", range(4))
+def test_engine_ready_steps_full_map_bit_identical(seed, shape):
+    """FullMap closed form == ready_steps_analytical (step and ready0),
+    batched == per-candidate, and both == the exhaustive traversal on the
+    valid entries, for consumers whose grid differs from the producer's."""
+    rng = random.Random(seed)
+    arch = small_arch(4)
+    if shape == "conv":
+        lp = LayerSpec("p", K=rng.choice([2, 4]), C=2, P=4, Q=4, R=3, S=3,
+                       pad=1)
+        lc = LayerSpec("c", K=2, C=4, P=2, Q=2, R=3, S=3, stride=2, pad=1)
+    else:
+        lp = LayerSpec("p", K=rng.choice([4, 8]), C=4, P=4, Q=1)
+        lc = LayerSpec("c", K=4, C=2, P=2, Q=1, N=2)
+    mp = random_mapping(lp, arch, rng, 32)
+    cands = [random_mapping(lc, arch, rng, ms) for ms in (4, 16, 32, 32)]
+    assert any((m.n_banks, m.n_steps) != (mp.n_banks, mp.n_steps)
+               for m in cands)
+    cm = FullMap()
+    got = OverlapEngine().ready_steps_batch(mp, cands, cm)
+    for mc, (sb, rb) in zip(cands, got):
+        sa, ra = ready_steps_analytical(mp, mc, cm)
+        se, re = OverlapEngine().ready_steps(mp, mc, cm)
+        sx, rx = ready_steps_exhaustive(mp, mc, cm)
+        assert se.shape == sa.shape == (mc.n_banks, mc.n_steps)
+        assert se.dtype == sa.dtype == np.int64
+        assert np.array_equal(ra, re) and np.array_equal(sa, se)
+        assert np.array_equal(rb, re) and np.array_equal(sb, se)
+        assert not ra.any() and np.array_equal(rx, ra)
+        assert np.array_equal(sx[~rx], se[~rx])
 
 
 @pytest.mark.parametrize("seed", range(4))

@@ -23,7 +23,7 @@ from repro import obs
 from repro.core import (Edge, FullMap, LayerSpec, SearchConfig,
                         chain_edges, dram_pim, optimize_network)
 from repro.core.engine import OverlapEngine, optimize_network_engine
-from repro.core.search import _consumers_of, candidates
+from repro.core.search import _consumers_of, _score_forward, candidates
 from repro.dse import (DSEConfig, DistribConfig, ParamSpace,
                        run_distributed, run_dse)
 from repro.obs import (Registry, TraceSink, merge_snapshots, quantile,
@@ -480,6 +480,45 @@ def _mixed_net():
     layers = _conv_chain()
     edges = [[], [Edge(0)], [Edge(1, FullMap())]]
     return layers, edges
+
+
+@pytest.mark.parametrize("mode", ["overlap", "transform"])
+def test_full_map_ready_steps_take_the_closed_form(mode):
+    """The FullMap layer's ready steps come from the producer alone:
+    ``ready_full`` grows, no consumer tile is projected, the scores are
+    the reference's, and the count is published as
+    ``engine.ready_full``. An identity-only chain never takes it."""
+    layers, edges = _mixed_net()
+    arch = _small_arch()
+    cfg = SearchConfig(n_candidates=8, seed=0, max_steps=512, mode=mode,
+                       use_engine=False)
+    done = dict(enumerate(optimize_network(layers, edges, arch,
+                                           cfg).layers))
+    eng = OverlapEngine()
+    eng.score_forward_batch(1, candidates(layers[1], arch, cfg, salt=1),
+                            edges, done, mode)
+    assert eng.stats["ready_full"] == 0
+    proj_miss = eng.stats["proj_miss"]
+    pool = candidates(layers[2], arch, cfg, salt=2)
+    got = eng.score_forward_batch(2, pool, edges, done, mode, False)
+    assert eng.stats["ready_full"] == len({m.cache_key for m in pool})
+    assert eng.stats["proj_miss"] == proj_miss
+    assert list(got) == [_score_forward(2, m, edges, done, mode, False)
+                         for m in pool]
+    reg = Registry()
+    eng.publish_metrics(registry=reg)
+    assert reg.snapshot()["counters"]["engine.ready_full"] \
+        == eng.stats["ready_full"]
+
+    ident = OverlapEngine()
+    chain = _conv_chain()
+    optimize_network_engine(chain, chain_edges(chain), arch, cfg,
+                            engine=ident)
+    assert ident.stats["ready_miss"] > 0
+    assert ident.stats["ready_full"] == 0
+    reg = Registry()
+    ident.publish_metrics(registry=reg)
+    assert "engine.ready_full" not in reg.snapshot()["counters"]
 
 
 def test_publish_metrics_times_follow_their_counts():

@@ -88,7 +88,8 @@ def test_engine_matches_reference_all_smoke(arch_id, phase):
 @pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize(
     "scenario", ["deepseek_moe_16b_smoke:decode@16",
-                 "mamba2_780m_smoke:prefill@32"])
+                 "mamba2_780m_smoke:prefill@32",
+                 "granite_moe_1b_a400m_smoke:decode@16"])
 def test_engine_matches_reference_modes_objectives(scenario, mode,
                                                    objective):
     """MoE fan-out and SSD topologies under every (mode, objective):
