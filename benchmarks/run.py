@@ -729,10 +729,11 @@ def workloads_main(argv) -> None:
         cfg = sc.config()
         layers, _ = lower_scenario(sc)
         macs = sum(l.macs for l in layers)
-        if cfg.family in ("hybrid", "audio"):
+        if cfg.family in ("hybrid", "audio") or cfg.n_dense_layers:
             # the lowered tranche mixes block kinds with different
-            # repeat counts (SSM vs shared-attention / enc vs dec), so
-            # a single whole-model multiplier would mislead
+            # repeat counts (SSM vs shared-attention / enc vs dec /
+            # leading dense vs MoE), so a single whole-model multiplier
+            # would mislead
             blocks_s, total_s = "mixed", "-"
         else:
             blocks_s = str(max(1, cfg.n_layers))

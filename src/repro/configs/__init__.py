@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned configs + input-shape cells.
+"""Architecture registry: the zoo's 11 configs + input-shape cells.
 
 Every config cites its public source (see per-file docstrings). Use
 ``get_config(arch_id)`` for the full config and
@@ -24,6 +24,7 @@ ARCH_IDS = (
     "granite_8b",
     "whisper_base",
     "llava_next_34b",
+    "deepseek_v2",
 )
 
 # dashed aliases as listed in the assignment

@@ -82,14 +82,15 @@ _GRID_GUARD = 1 << 19
 # to the obs registry at search boundaries)
 _STAT_KEYS = ("tiles_hit", "tiles_miss", "tail_hit", "tail_miss",
               "proj_hit", "proj_miss", "ready_hit", "ready_miss",
-              "ready_full",
+              "ready_full", "ready_cmap",
               "sepcls_hit", "sepcls_miss", "score_hit", "score_miss",
               "score_pool_hit", "batch_scored", "dense_scored",
               "guard_fallback", "evictions", "perf_hit", "perf_miss")
 # engine-local stage timers (plain float seconds in ``OverlapEngine.times``,
 # published beside ``stats`` as float counters): the batched class-histogram
-# scorer, and the dense per-candidate path with its ready-step pass
-_TIME_KEYS = ("score_batch_s", "score_dense_s")
+# scorer, the dense per-candidate path with its ready-step pass, and,
+# inside that pass, the generic-coordinate-map ready matrices
+_TIME_KEYS = ("score_batch_s", "score_dense_s", "ready_cmap_s")
 
 
 def _unique_inverse(codes: np.ndarray, bound: int):
@@ -892,7 +893,11 @@ class OverlapEngine:
                 self.stats["ready_miss"] += 1
                 todo.setdefault(key, []).append(k)  # dedupes equal mappings
         if todo:
+            # generic coordinate maps (head folds, weight maps): one
+            # timer pair per call, one count per distinct ready matrix
+            t0 = time.perf_counter()
             keys = list(todo)
+            self.stats["ready_cmap"] += len(keys)
             reps = [cands[todo[key][0]] for key in keys]
             projs = self._projection_batch(reps, cmap, m_p.layer)
             cat_lo = {d: np.concatenate([p[0][d].reshape(-1) for p in projs])
@@ -908,6 +913,7 @@ class OverlapEngine:
                 self._cur.ready[key] = (step, ready0)
                 for k in todo[key]:
                     out[k] = (step, ready0)
+            self.times["ready_cmap_s"] += time.perf_counter() - t0
         return out
 
     def _prod_ranks(self, prod: LayerResult):

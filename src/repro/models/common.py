@@ -61,6 +61,23 @@ class ModelConfig:
     # expert reshard is one all-to-all instead of an all-gather of the
     # full [E, cap, D] slot tensor.
     moe_expert_axis: str = ""
+    # routed experts fall into this many groups (DeepSeek-V2 ``n_group``,
+    # device-limited routing); a token's experts come from ``topk_groups``
+    # of them
+    n_expert_groups: int = 0
+    topk_groups: int = 0
+    # leading dense blocks before the MoE blocks (DeepSeek
+    # ``first_k_dense_replace``) and their MLP width
+    n_dense_layers: int = 0
+    d_ff_dense: int = 0
+    # multi-head latent attention (MLA, arXiv:2405.04434 Sec. 2.1): the
+    # query and key/value low ranks and the per-head dims of the rope and
+    # nope query/key parts and of the value; kv_lora_rank > 0 selects MLA
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # SSM (Mamba-2 / SSD)
     ssm_state: int = 0
     ssm_expand: int = 2

@@ -1,7 +1,10 @@
-"""Family dispatch: one API across all 10 architectures.
+"""Family dispatch: one API across the zoo's architectures.
 
 ``audio`` (encoder-decoder) dispatches to ``encdec``; everything else to
-``lm``. All functions are pure and jit-friendly.
+``lm``. All functions are pure and jit-friendly. The substrate builds
+GQA/MQA attention only: a config with multi-head latent attention
+(``kv_lora_rank > 0``, DeepSeek-V2) is refused rather than built as GQA
+under its name; such a config is served by ``repro.workloads`` alone.
 """
 from __future__ import annotations
 
@@ -15,13 +18,25 @@ from .common import ModelConfig
 PyTree = Any
 
 
+def _check_buildable(cfg: ModelConfig) -> None:
+    """Raise ``NotImplementedError`` for a config the substrate cannot
+    build faithfully (latent attention). The constructors of parameters
+    and caches call it, so nothing downstream runs on such a config."""
+    if cfg.kv_lora_rank:
+        raise NotImplementedError(
+            f"{cfg.arch_id}: the LM substrate has no multi-head latent "
+            "attention (kv_lora_rank > 0); lower it with repro.workloads")
+
+
 def init_params(cfg: ModelConfig, key) -> PyTree:
+    _check_buildable(cfg)
     if cfg.family == "audio":
         return encdec.init_params(cfg, key)
     return lm.init_params(cfg, key)
 
 
 def param_shapes(cfg: ModelConfig) -> PyTree:
+    _check_buildable(cfg)
     if cfg.family == "audio":
         return encdec.param_shapes(cfg)
     return lm.param_shapes(cfg)
@@ -42,6 +57,7 @@ def forward(cfg: ModelConfig, params: PyTree, batch: Dict):
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int) -> PyTree:
+    _check_buildable(cfg)
     if cfg.family == "audio":
         return encdec.init_cache(cfg, batch, max_seq, cfg.enc_frames)
     return lm.init_cache(cfg, batch, max_seq)

@@ -10,7 +10,8 @@ names the interesting shapes (``deepseek_moe_16b:prefill@2048``,
 ``MappingRequest`` — accepts the whole zoo unchanged. Conventions are
 specified in DESIGN.md Section 15.
 """
-from .lowering import (NetBuilder, PHASES, lower, moe_capacity)
+from .lowering import (NetBuilder, PHASES, expert_range, lower,
+                       moe_capacity)
 from .scenarios import (DEFAULT_DECODE_KV, DEFAULT_PREFILL_SEQ,
                         SMOKE_DECODE_KV, SMOKE_PREFILL_SEQ, Scenario,
                         describe_scenario, is_scenario_name,

@@ -36,6 +36,14 @@ Conventions (DESIGN.md Section 15):
   the conservative ``FullMap`` (consumer waits for the producer's whole
   output) — correct, just overlap-pessimistic, and documented per edge
   below.
+* **Latent attention.** A config with ``kv_lora_rank > 0`` lowers MLA
+  (``_mla``): decompressed at prefill, absorbed at decode, where it
+  attends over the latent cache itself.
+* **Expert shares.** ``share = (s, n)`` lowers a device that holds
+  routed-expert share s of n equal shares of each MoE layer
+  (``expert_range``); router, capacity, attention and shared experts stay
+  those of the whole layer, and the exchange between devices is not
+  lowered.
 """
 from __future__ import annotations
 
@@ -149,29 +157,130 @@ def _attention(b: NetBuilder, cfg: ModelConfig, inputs: Sequence[Producer],
     return [(out, "identity")]
 
 
+def _mla(b: NetBuilder, cfg: ModelConfig, inputs: Sequence[Producer],
+         prefix: str, q_len: int, kv_len: int) -> List[Producer]:
+    """Multi-head latent attention (arXiv:2405.04434 Sec. 2.1).
+
+    Queries and keys/values pass through low-rank latents (``q_down`` to
+    ``q_lora_rank``, ``kv_down`` to ``kv_lora_rank``); W^Q/W^QR and
+    W^DKV/W^KR are split by output column into separate matmuls (exact,
+    and each slice keeps its own head dim, so ``HeadFoldMap`` stays exact
+    per slice). The decoupled rope key ``k_rope`` is one head shared by
+    all heads. Norms, softmax and RoPE are elementwise, excluded.
+
+    * prefill, decompressed: ``k_up``/``v_up`` expand the latent per
+      head; ``qk_nope`` reads the queries through ``HeadFoldMap`` and
+      ``k_up`` as its stationary operand (``WeightMap`` group 1),
+      ``qk_rope`` reads the shared rope key with ``group = n_heads``;
+      ``av`` reads ``v_up`` as an ``av_weight``.
+    * decode, absorbed: W^UK folds into the query (``q_absorb``, per
+      head ``qk_nope_head_dim -> kv_lora_rank``) and W^UV into the
+      output (``v_up`` after ``av_lat``), so the step attends over the
+      latent cache of ``kv_lora_rank + qk_rope_head_dim`` numbers per
+      token. The cache predates the request; the fresh token's latent and
+      rope key reach it through ``FullMap`` appends, as in GQA decode."""
+    h, r = cfg.n_heads, cfg.qk_rope_head_dim
+    nope, vd, lat = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    deps = [_edge(i, k) for i, k in inputs]
+    q_down = b.add(matmul(f"{prefix}q_down", q_len, cfg.d_model,
+                          cfg.q_lora_rank), deps)
+    q_nope = b.add(matmul(f"{prefix}q_nope_up", q_len, cfg.q_lora_rank,
+                          h * nope), [Edge(q_down, IdentityMap())])
+    q_rope = b.add(matmul(f"{prefix}q_rope_up", q_len, cfg.q_lora_rank,
+                          h * r), [Edge(q_down, IdentityMap())])
+    kv_down = b.add(matmul(f"{prefix}kv_down", q_len, cfg.d_model, lat),
+                    deps)
+    k_rope = b.add(matmul(f"{prefix}k_rope", q_len, cfg.d_model, r), deps)
+    if q_len == 1 and kv_len > q_len:
+        q_abs = b.add(matmul(f"{prefix}q_absorb", 1, nope, lat, batch=h),
+                      [Edge(q_nope, HeadFoldMap(1, nope))])
+        qk_lat = b.add(matmul(f"{prefix}qk_lat", 1, lat, kv_len, batch=h),
+                       [Edge(q_abs, IdentityMap()),
+                        Edge(kv_down, FullMap())])
+        qk_rope = b.add(matmul(f"{prefix}qk_rope", 1, r, kv_len, batch=h),
+                        [Edge(q_rope, HeadFoldMap(1, r)),
+                         Edge(k_rope, FullMap())])
+        av = b.add(matmul(f"{prefix}av_lat", 1, kv_len, lat, batch=h),
+                   [Edge(qk_lat, IdentityMap()), Edge(qk_rope, IdentityMap()),
+                    Edge(kv_down, FullMap())])
+        attn = b.add(matmul(f"{prefix}v_up", 1, lat, vd, batch=h),
+                     [Edge(av, IdentityMap())])
+    else:
+        k_up = b.add(matmul(f"{prefix}k_up", q_len, lat, h * nope),
+                     [Edge(kv_down, IdentityMap())])
+        v_up = b.add(matmul(f"{prefix}v_up", q_len, lat, h * vd),
+                     [Edge(kv_down, IdentityMap())])
+        qk_nope = b.add(
+            matmul(f"{prefix}qk_nope", q_len, nope, kv_len, batch=h),
+            [Edge(q_nope, HeadFoldMap(q_len, nope)),
+             Edge(k_up, WeightMap(q_len, nope, "qk_weight", 1))])
+        qk_rope = b.add(
+            matmul(f"{prefix}qk_rope", q_len, r, kv_len, batch=h),
+            [Edge(q_rope, HeadFoldMap(q_len, r)),
+             Edge(k_rope, WeightMap(q_len, r, "qk_weight", h))])
+        attn = b.add(matmul(f"{prefix}av", q_len, kv_len, vd, batch=h),
+                     [Edge(qk_nope, IdentityMap()),
+                      Edge(qk_rope, IdentityMap()),
+                      Edge(v_up, WeightMap(q_len, vd, "av_weight", 1))])
+    out = b.add(matmul(f"{prefix}o_proj", q_len, h * vd, cfg.d_model),
+                [Edge(attn, HeadUnfoldMap(q_len, vd))])
+    return [(out, "identity")]
+
+
+def _self_attention(b: NetBuilder, cfg: ModelConfig,
+                    inputs: Sequence[Producer], prefix: str,
+                    q_len: int, kv_len: int) -> List[Producer]:
+    """The config's self-attention sublayer: MLA where it has a KV
+    latent (``kv_lora_rank > 0``), else GQA/MQA."""
+    if cfg.kv_lora_rank:
+        return _mla(b, cfg, inputs, prefix, q_len, kv_len)
+    return _attention(b, cfg, inputs, prefix, q_len, kv_len)
+
+
 def _dense_block(b: NetBuilder, cfg: ModelConfig,
                  inputs: Sequence[Producer], prefix: str,
-                 q_len: int, kv_len: int) -> List[Producer]:
-    """Attention + MLP — the dense/vlm decoder block (and zamba2's shared
-    attention block)."""
-    attn = _attention(b, cfg, inputs, prefix, q_len, kv_len)
-    return _ffn(b, cfg, attn, prefix, q_len, cfg.d_model, cfg.d_ff)
+                 q_len: int, kv_len: int,
+                 d_ff: Optional[int] = None) -> List[Producer]:
+    """Attention + MLP — the dense/vlm decoder block, zamba2's shared
+    attention block, and a MoE model's leading dense blocks (``d_ff``
+    their own MLP width)."""
+    attn = _self_attention(b, cfg, inputs, prefix, q_len, kv_len)
+    return _ffn(b, cfg, attn, prefix, q_len, cfg.d_model, d_ff or cfg.d_ff)
+
+
+def expert_range(cfg: ModelConfig, share: Tuple[int, int] = (0, 1)
+                 ) -> range:
+    """Routed experts held by share ``share[0]`` of ``share[1]`` equal
+    shares of each MoE layer (expert parallelism)."""
+    s, n = share
+    if n < 1 or not 0 <= s < n or cfg.n_experts % n \
+            or (n > 1 and not cfg.n_experts):
+        raise ValueError(f"{cfg.arch_id}: {cfg.n_experts} routed experts "
+                         f"do not split into share {s} of {n}")
+    per = cfg.n_experts // n
+    return range(s * per, (s + 1) * per)
 
 
 def _moe_block(b: NetBuilder, cfg: ModelConfig,
                inputs: Sequence[Producer], prefix: str,
-               q_len: int, kv_len: int) -> List[Producer]:
+               q_len: int, kv_len: int,
+               share: Tuple[int, int] = (0, 1)) -> List[Producer]:
     """Attention + router + shared experts + top-k routed expert fan-out.
 
     The router is a plain ``tokens x d_model x n_experts`` matmul (its
     softmax/top-k select is elementwise, excluded). Shared experts see
-    every token in order (exact identity edges); each of the
-    ``n_experts`` routed experts is lowered at its ``moe_capacity`` slot
-    count with ``FullMap`` fan-out edges from both the router (dispatch
-    waits on routing values) and the attention output (token gather).
-    The combine is a scatter-add, so expert outputs re-enter downstream
-    consumers as ``full`` producers (fan-in)."""
-    attn = _attention(b, cfg, inputs, prefix, q_len, kv_len)
+    every token in order (exact identity edges); each routed expert of
+    this device's ``share`` (``expert_range``; all ``n_experts`` when
+    unshared) is lowered at its ``moe_capacity`` slot count with
+    ``FullMap`` fan-out edges from both the router (dispatch waits on
+    routing values) and the attention output (token gather). The router
+    and the capacity keep the whole layer's ``n_experts``; attention and
+    shared experts are whole on every device, and the exchange between
+    devices is not lowered. The combine is a scatter-add, so expert
+    outputs re-enter downstream consumers as ``full`` producers
+    (fan-in)."""
+    experts = expert_range(cfg, share)
+    attn = _self_attention(b, cfg, inputs, prefix, q_len, kv_len)
     attn_deps = [_edge(i, k) for i, k in attn]
     router = b.add(matmul(f"{prefix}router", q_len, cfg.d_model,
                           cfg.n_experts), attn_deps)
@@ -182,7 +291,7 @@ def _moe_block(b: NetBuilder, cfg: ModelConfig,
     cap = moe_capacity(cfg, q_len)
     fan_out: List[Producer] = [(router, "full")] + \
         [(i, "full") for i, _ in attn]
-    for e in range(cfg.n_experts):
+    for e in experts:
         (down, _), = _ffn(b, cfg, fan_out, f"{prefix}exp{e}.", cap,
                           cfg.d_model, cfg.d_ff)
         outs.append((down, "full"))
@@ -311,7 +420,8 @@ def _audio_net(b: NetBuilder, cfg: ModelConfig, phase: str,
 
 
 def lower(cfg: ModelConfig, phase: str = "prefill", seq: int = 2048,
-          kv_len: int = 1024, blocks: int = 1
+          kv_len: int = 1024, blocks: int = 1,
+          share: Tuple[int, int] = (0, 1)
           ) -> Tuple[List[LayerSpec], List[List[Edge]]]:
     """Lower ``blocks`` tranche blocks of ``cfg`` into (layers, edges).
 
@@ -320,14 +430,17 @@ def lower(cfg: ModelConfig, phase: str = "prefill", seq: int = 2048,
     is independent of ``seq`` by construction. Families: ``dense``/
     ``vlm`` -> attention+MLP blocks (vlm prefill prepends the vision
     frontend and its ``img_tokens``), ``moe`` -> attention + shared/
-    routed expert fan-out, ``ssm`` -> SSD skeleton, ``hybrid`` -> one
-    SSD block + the shared attention block per tranche, ``audio`` ->
-    whisper stem/encoder/decoder."""
+    routed expert fan-out, the first ``n_dense_layers`` of the chain
+    dense blocks at ``d_ff_dense``, ``ssm`` -> SSD skeleton, ``hybrid``
+    -> one SSD block + the shared attention block per tranche,
+    ``audio`` -> whisper stem/encoder/decoder. ``share`` = (s, n): this
+    device holds routed-expert share s of n (``expert_range``)."""
     if phase not in PHASES:
         raise ValueError(f"phase must be one of {PHASES}, got {phase!r}")
     if seq < 1 or kv_len < 1 or blocks < 1:
         raise ValueError(f"seq/kv_len/blocks must be >= 1, got "
                          f"{seq}/{kv_len}/{blocks}")
+    expert_range(cfg, share)
     b = NetBuilder()
     fam = cfg.family
     if fam == "audio":
@@ -340,8 +453,11 @@ def lower(cfg: ModelConfig, phase: str = "prefill", seq: int = 2048,
     q_len, kv = (seq, seq) if phase == "prefill" else (1, kv_len)
     for i in range(blocks):
         pre = f"b{i}." if blocks > 1 else ""
-        if fam == "moe":
-            inputs = _moe_block(b, cfg, inputs, pre, q_len, kv)
+        if fam == "moe" and i < cfg.n_dense_layers:
+            inputs = _dense_block(b, cfg, inputs, pre, q_len, kv,
+                                  cfg.d_ff_dense)
+        elif fam == "moe":
+            inputs = _moe_block(b, cfg, inputs, pre, q_len, kv, share)
         elif fam == "ssm":
             inputs = _ssd_block(b, cfg, inputs, pre, phase, q_len)
         elif fam == "hybrid":

@@ -7,10 +7,13 @@ describe``, ``core.workload.get_network``, ``run.py dse --network``, a
     deepseek_moe_16b:prefill@2048      # 2048-token prompt, one MoE block
     mamba2_780m:decode@1               # one decode step
     granite_8b_smoke:prefill@64x2      # smoke config, two chained blocks
+    deepseek_v2_ep8:decode@32768x5     # one of 8 routed-expert shares
 
 Grammar: ``<arch>[:phase][@length][xblocks]`` where ``arch`` is a zoo id
 (dashes allowed, ``_smoke``/``-smoke`` suffix selects the reduced
-same-family smoke config), ``phase`` defaults to ``prefill``, ``length``
+same-family smoke config, a further ``_ep<N>`` suffix says this device
+holds one of N equal shares of each layer's routed experts, see
+``lowering.expert_range``), ``phase`` defaults to ``prefill``, ``length``
 is the prompt length (prefill) or KV/context length (decode) and
 ``blocks`` chains that many tranche blocks. Defaults and the canonical
 per-arch names live in ``list_scenarios``.
@@ -25,7 +28,7 @@ from ..configs import ARCH_IDS, get_config
 from ..core.interface import NetworkDesc
 from ..core.workload import LayerSpec
 from ..models.common import ModelConfig
-from .lowering import PHASES, lower
+from .lowering import PHASES, expert_range, lower
 
 #: default lengths of scenario names that omit ``@length``
 DEFAULT_PREFILL_SEQ = 2048
@@ -49,12 +52,14 @@ class Scenario:
     phase: str                   # prefill | decode
     length: int                  # seq (prefill) / kv context (decode)
     blocks: int = 1
+    ep: int = 1                  # routed-expert shares per MoE layer
 
     @property
     def name(self) -> str:
         """Canonical round-trippable scenario string."""
         suffix = "" if self.blocks == 1 else f"x{self.blocks}"
-        arch = self.arch_id + ("_smoke" if self.smoke else "")
+        arch = self.arch_id + ("_smoke" if self.smoke else "") \
+            + ("" if self.ep == 1 else f"_ep{self.ep}")
         return f"{arch}:{self.phase}@{self.length}{suffix}"
 
     def config(self) -> ModelConfig:
@@ -62,13 +67,21 @@ class Scenario:
         return get_config(self.arch_id, smoke=self.smoke)
 
 
-def _resolve_arch(token: str) -> Optional[Tuple[str, bool]]:
-    """Zoo id + smoke flag of an arch token, or None if unknown."""
+_EP_RE = re.compile(r"_ep(\d+)$")
+
+
+def _resolve_arch(token: str) -> Optional[Tuple[str, bool, int]]:
+    """Zoo id, smoke flag and expert shares of an arch token, or None if
+    unknown."""
     norm = token.replace("-", "_")
+    m = _EP_RE.search(norm)
+    ep = int(m.group(1)) if m else 1
+    if m:
+        norm = norm[:m.start()]
     smoke = norm.endswith("_smoke")
     if smoke:
         norm = norm[:-len("_smoke")]
-    return (norm, smoke) if norm in ARCH_IDS else None
+    return (norm, smoke, ep) if norm in ARCH_IDS else None
 
 
 def parse_scenario(name: str, *, seq: Optional[int] = None,
@@ -83,7 +96,9 @@ def parse_scenario(name: str, *, seq: Optional[int] = None,
         raise KeyError(f"unknown network/scenario {name!r}; zoo archs: "
                        f"{list(ARCH_IDS)} (grammar: "
                        "'<arch>[:phase][@length][xblocks]')")
-    arch_id, smoke = arch
+    arch_id, smoke, ep = arch
+    if ep != 1:
+        expert_range(get_config(arch_id, smoke=smoke), (0, ep))
     phase = m.group("phase") or "prefill"
     if phase not in PHASES:
         raise ValueError(f"scenario {name!r}: phase must be one of "
@@ -103,7 +118,7 @@ def parse_scenario(name: str, *, seq: Optional[int] = None,
         raise ValueError(f"scenario {name!r}: length and blocks must be "
                          f">= 1, got {length}/{n_blocks}")
     return Scenario(arch_id=arch_id, smoke=smoke, phase=phase,
-                    length=length, blocks=n_blocks)
+                    length=length, blocks=n_blocks, ep=ep)
 
 
 def is_scenario_name(name: str) -> bool:
@@ -119,9 +134,12 @@ def is_scenario_name(name: str) -> bool:
 def lower_scenario(sc: Scenario) -> Tuple[List[LayerSpec], list]:
     """(layers, edges) of one parsed scenario."""
     cfg = sc.config()
+    share = (0, sc.ep)
     if sc.phase == "prefill":
-        return lower(cfg, "prefill", seq=sc.length, blocks=sc.blocks)
-    return lower(cfg, "decode", kv_len=sc.length, blocks=sc.blocks)
+        return lower(cfg, "prefill", seq=sc.length, blocks=sc.blocks,
+                     share=share)
+    return lower(cfg, "decode", kv_len=sc.length, blocks=sc.blocks,
+                 share=share)
 
 
 def describe_scenario(name: str, **kw) -> NetworkDesc:

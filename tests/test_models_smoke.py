@@ -12,19 +12,21 @@ from repro.models import model_zoo
 from repro.models.inputs import make_decode_tokens, make_train_batch
 
 B, S = 2, 32
+#: the archs the LM substrate builds (latent attention is lowered only)
+LM_ARCHS = [a for a in ARCH_IDS if not get_config(a).kv_lora_rank]
 
 
 @pytest.fixture(scope="module")
 def zoo():
     out = {}
-    for a in ARCH_IDS:
+    for a in LM_ARCHS:
         cfg = get_config(a, smoke=True)
         params = model_zoo.init_params(cfg, jax.random.PRNGKey(0))
         out[a] = (cfg, params)
     return out
 
 
-@pytest.mark.parametrize("arch", ARCH_IDS)
+@pytest.mark.parametrize("arch", LM_ARCHS)
 def test_forward_shapes_and_finite(zoo, arch):
     cfg, params = zoo[arch]
     batch = make_train_batch(cfg, B, S)
@@ -34,7 +36,7 @@ def test_forward_shapes_and_finite(zoo, arch):
     assert bool(jnp.isfinite(aux))
 
 
-@pytest.mark.parametrize("arch", ARCH_IDS)
+@pytest.mark.parametrize("arch", LM_ARCHS)
 def test_loss_and_grads_finite(zoo, arch):
     cfg, params = zoo[arch]
     batch = make_train_batch(cfg, B, S)
@@ -48,7 +50,7 @@ def test_loss_and_grads_finite(zoo, arch):
         < 2.5 * np.log(cfg.vocab)
 
 
-@pytest.mark.parametrize("arch", ARCH_IDS)
+@pytest.mark.parametrize("arch", LM_ARCHS)
 def test_decode_step(zoo, arch):
     cfg, params = zoo[arch]
     cache = model_zoo.init_cache(cfg, B, S)
@@ -64,6 +66,18 @@ def test_decode_step(zoo, arch):
     assert int(cache2["pos"]) == 1
     logits3, _ = model_zoo.decode_step(cfg, params, cache2, toks)
     assert bool(jnp.isfinite(logits3.astype(jnp.float32)).all())
+
+
+@pytest.mark.parametrize("smoke", [True, False])
+def test_latent_attention_config_is_refused(smoke):
+    """DeepSeek-V2's MLA is not built as GQA under its name: parameters,
+    their shapes and a cache are all refused."""
+    cfg = get_config("deepseek_v2", smoke=smoke)
+    for build in (lambda: model_zoo.init_params(cfg, jax.random.PRNGKey(0)),
+                  lambda: model_zoo.param_shapes(cfg),
+                  lambda: model_zoo.init_cache(cfg, B, S)):
+        with pytest.raises(NotImplementedError, match="latent attention"):
+            build()
 
 
 def test_vlm_image_embeds_path(zoo):

@@ -21,7 +21,7 @@ import pytest
 
 from repro import obs
 from repro.core import (Edge, FullMap, LayerSpec, SearchConfig,
-                        chain_edges, dram_pim, optimize_network)
+                        chain_edges, describe, dram_pim, optimize_network)
 from repro.core.engine import OverlapEngine, optimize_network_engine
 from repro.core.search import _consumers_of, _score_forward, candidates
 from repro.dse import (DSEConfig, DistribConfig, ParamSpace,
@@ -519,6 +519,31 @@ def test_full_map_ready_steps_take_the_closed_form(mode):
     reg = Registry()
     ident.publish_metrics(registry=reg)
     assert "engine.ready_full" not in reg.snapshot()["counters"]
+
+
+@pytest.mark.parametrize("network,head_folds", [
+    ("granite_moe_1b_a400m_smoke:decode@16", True),
+    ("deepseek_v2_smoke_ep2:decode@16x2", True),
+    ("resnet18", False)])
+def test_ready_cmap_counts_generic_map_ready_matrices(network, head_folds):
+    """``engine.ready_cmap``/``ready_cmap_s`` count and time the ready
+    matrices computed through generic coordinate maps (head folds,
+    weight maps): non-zero on networks with such edges, zero on an
+    identity-only network, and at most one per ready-cache miss."""
+    desc = describe(network)
+    eng = OverlapEngine()
+    cfg = SearchConfig(n_candidates=4, seed=0, max_steps=256,
+                       mode="transform")
+    optimize_network_engine(desc.layers, desc.edges, _small_arch(), cfg,
+                            engine=eng)
+    assert (eng.stats["ready_cmap"] > 0) is head_folds
+    assert (eng.times["ready_cmap_s"] > 0) is head_folds
+    assert eng.stats["ready_cmap"] <= eng.stats["ready_miss"]
+    reg = Registry()
+    eng.publish_metrics(registry=reg)
+    got = reg.snapshot()["counters"]
+    assert got.get("engine.ready_cmap", 0) == eng.stats["ready_cmap"]
+    assert got.get("engine.ready_cmap_s", 0) == eng.times["ready_cmap_s"]
 
 
 def test_publish_metrics_times_follow_their_counts():

@@ -84,6 +84,20 @@ def test_engine_matches_reference_all_smoke(arch_id, phase):
     assert_results_identical(a, b)
 
 
+@pytest.mark.parametrize("scenario", ["deepseek_v2_smoke:decode@16x2",
+                                      "deepseek_v2_smoke:prefill@32x2",
+                                      "deepseek_v2_smoke_ep2:decode@16x2"])
+def test_engine_matches_reference_mla(scenario):
+    """MLA's head folds at two head dims, the leading dense block and an
+    expert share: engine and reference agree bit for bit."""
+    desc = describe(scenario)
+    c = cfg()
+    a = optimize_network(desc.layers, desc.edges, small_arch(), c)
+    b = optimize_network(desc.layers, desc.edges, small_arch(),
+                         dataclasses.replace(c, use_engine=False))
+    assert_results_identical(a, b)
+
+
 @pytest.mark.parametrize("objective", OBJECTIVES)
 @pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize(
@@ -125,13 +139,41 @@ def _attn_macs(c, q, kv, kv_proj_tokens):
             + q * h * hd * c.d_model)
 
 
-def _moe_macs(c, q, kv):
+def _mla_macs(c, q, kv):
+    """MLA (arXiv:2405.04434 Sec. 2.1, Appendix C) over ``q`` query
+    tokens and ``kv`` keys. The projections W^DQ, W^UQ, W^QR, W^DKV, W^KR
+    and W^O act on the ``q`` new tokens. Prefill decompresses: W^UK and
+    W^UV act on every token, and each head's scores and weighted sum run
+    over [q^C; q^R] of width nope + rope and v^C of width v. Decode over
+    a cache absorbs W^UK into the query (H nope x lat per token) and W^UV
+    into the output (H lat x v), so the scores and the weighted sum run
+    over the cached latent (width lat) and the shared rope key."""
+    h, r, lat = c.n_heads, c.qk_rope_head_dim, c.kv_lora_rank
+    nope, v, d, dq = c.qk_nope_head_dim, c.v_head_dim, c.d_model, \
+        c.q_lora_rank
+    proj = q * (d * dq + dq * h * (nope + r) + d * lat + d * r
+                + h * v * d)
+    if q == 1 and kv > 1:
+        return (proj + h * nope * lat + h * kv * (lat + r)
+                + h * kv * lat + h * lat * v)
+    return (proj + kv * lat * h * (nope + v)
+            + h * q * kv * (nope + r) + h * q * kv * v)
+
+
+def _self_attn_macs(c, q, kv):
+    if c.kv_lora_rank:
+        return _mla_macs(c, q, kv)
+    return _attn_macs(c, q, kv, q if q == kv else 1)
+
+
+def _moe_macs(c, q, kv, shares=1):
     cap = max(1, math.ceil(q / max(c.moe_shards, 1) * c.top_k
                            / c.n_experts * c.capacity_factor))
-    return (_attn_macs(c, q, kv, q if q == kv else 1)
+    return (_self_attn_macs(c, q, kv)
             + q * c.d_model * c.n_experts
             + c.n_shared_experts * _ffn_macs(c, q)
-            + c.n_experts * _FAC[c.mlp] * cap * c.d_model * c.d_ff)
+            + c.n_experts // shares * _FAC[c.mlp] * cap * c.d_model
+            * c.d_ff)
 
 
 def _ssd_macs(c, phase, tokens):
@@ -164,8 +206,10 @@ def _audio_macs(c, phase, length, blocks):
     return blocks * dec
 
 
-def analytic_macs(c, phase, length, blocks=1):
-    """Independent per-model MAC count of ``lower(c, phase, ...)``."""
+def analytic_macs(c, phase, length, blocks=1, shares=1):
+    """Independent per-model MAC count of ``lower(c, phase, ...)``; with
+    ``shares`` the MoE blocks hold one of that many routed-expert
+    shares."""
     fam = c.family
     if fam == "audio":
         return _audio_macs(c, phase, length, blocks)
@@ -179,7 +223,10 @@ def analytic_macs(c, phase, length, blocks=1):
         length = length + c.img_tokens
     q, kv = (length, length) if phase == "prefill" else (1, length)
     if fam == "moe":
-        block = _moe_macs(c, q, kv)
+        dense = min(blocks, c.n_dense_layers)
+        return (dense * (_self_attn_macs(c, q, kv)
+                         + _FAC[c.mlp] * q * c.d_model * c.d_ff_dense)
+                + (blocks - dense) * _moe_macs(c, q, kv, shares))
     elif fam == "ssm":
         block = _ssd_macs(c, phase, q)
     elif fam == "hybrid":
@@ -205,7 +252,8 @@ def test_golden_mac_accounting(arch_id, phase, smoke):
 
 
 @pytest.mark.parametrize("arch_id", ["deepseek_moe_16b", "zamba2_1_2b",
-                                     "whisper_base", "llava_next_34b"])
+                                     "whisper_base", "llava_next_34b",
+                                     "deepseek_v2"])
 def test_golden_macs_multi_block(arch_id):
     """blocks=N scales the repeating tranche only — frontends (vision
     patch-embed, whisper stem+encoder) are lowered once."""
@@ -213,6 +261,20 @@ def test_golden_macs_multi_block(arch_id):
     layers, _ = lower(c, "prefill", seq=32, blocks=3)
     assert sum(l.macs for l in layers) == analytic_macs(c, "prefill", 32,
                                                         blocks=3)
+
+
+@pytest.mark.parametrize("phase,length", [("decode", 32768),
+                                          ("prefill", 512)])
+def test_golden_macs_deepseek_v2_expert_share(phase, length):
+    """The benchmark's shape: a dense block and four MoE blocks holding
+    one of eight routed-expert shares, at the published widths."""
+    c = get_config("deepseek_v2")
+    layers, _ = lower(c, phase, seq=length, kv_len=length, blocks=5,
+                      share=(0, 8))
+    assert sum(l.macs for l in layers) == \
+        analytic_macs(c, phase, length, blocks=5, shares=8)
+    if phase == "decode":
+        assert len(layers) == 14 + 4 * 78
 
 
 def test_moe_capacity_formula():
@@ -312,6 +374,15 @@ def test_weightmap_group_in_key():
 def test_scenario_roundtrip_and_defaults():
     sc = parse_scenario("deepseek_moe_16b:prefill@2048")
     assert sc.name == "deepseek_moe_16b:prefill@2048"
+    for name in ("deepseek_v2_ep8:decode@32768x5",
+                 "deepseek_v2_smoke_ep2:prefill@32x2",
+                 "granite_moe_1b_a400m_ep4:decode@4096"):
+        assert parse_scenario(name).name == name
+    assert parse_scenario("deepseek-v2-smoke-ep2:decode").ep == 2
+    assert parse_scenario("deepseek_v2_ep1:decode@16").name == \
+        "deepseek_v2:decode@16"
+    assert describe("deepseek_v2_ep8:decode@32768x5").name == \
+        "deepseek_v2_ep8:decode@32768x5"
     assert parse_scenario("mamba2_780m").phase == "prefill"
     assert parse_scenario("mamba2_780m_smoke:decode").length == 16
     assert parse_scenario("granite-8b-smoke:prefill@64x2").blocks == 2
@@ -324,6 +395,10 @@ def test_scenario_errors():
         parse_scenario("olmo_1b:training")
     with pytest.raises(ValueError):
         parse_scenario("olmo_1b:prefill@0")
+    with pytest.raises(ValueError):          # 160 experts, 7 shares
+        parse_scenario("deepseek_v2_ep7:decode")
+    with pytest.raises(ValueError):          # no routed experts
+        parse_scenario("olmo_1b_ep2:decode")
 
 
 def test_describe_rejects_kwargs_on_fixed_networks():
