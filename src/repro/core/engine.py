@@ -23,7 +23,9 @@ changing a single produced number (DESIGN.md Section 6):
    tile corners factor into bank + step parts, so the scan touches only
    distinct (bank value, step pair) combos. ``FullMap`` edges need no
    consumer tiles at all (``_ready_steps_full``): every tile projects onto
-   the producer's whole output, so the step is one producer-only integer.
+   the producer's whole output, so the step is one producer-only integer,
+   and a transform-mode pool on a layer whose edges are all ``FullMap``
+   is scored from that one ready constant (``_score_full_batch``).
 3. **Radix transform ordering** — single-edge ready matrices are ordered
    by producer finish-time rank, handing ``transform_schedule`` a
    precomputed stable integer argsort instead of a float mergesort.
@@ -84,13 +86,16 @@ _STAT_KEYS = ("tiles_hit", "tiles_miss", "tail_hit", "tail_miss",
               "proj_hit", "proj_miss", "ready_hit", "ready_miss",
               "ready_full", "ready_cmap",
               "sepcls_hit", "sepcls_miss", "score_hit", "score_miss",
-              "score_pool_hit", "batch_scored", "dense_scored",
-              "guard_fallback", "evictions", "perf_hit", "perf_miss")
+              "score_pool_hit", "batch_scored", "full_scored",
+              "dense_scored", "guard_fallback", "evictions", "perf_hit",
+              "perf_miss")
 # engine-local stage timers (plain float seconds in ``OverlapEngine.times``,
 # published beside ``stats`` as float counters): the batched class-histogram
-# scorer, the dense per-candidate path with its ready-step pass, and,
-# inside that pass, the generic-coordinate-map ready matrices
-_TIME_KEYS = ("score_batch_s", "score_dense_s", "ready_cmap_s")
+# scorer, the closed-form all-``FullMap`` scorer, the dense per-candidate
+# path with its ready-step pass, and, inside that pass, the
+# generic-coordinate-map ready matrices
+_TIME_KEYS = ("score_batch_s", "score_full_s", "score_dense_s",
+              "ready_cmap_s")
 
 
 def _unique_inverse(codes: np.ndarray, bound: int):
@@ -504,6 +509,14 @@ class OverlapEngine:
         to ``ready_steps_analytical``, which scans the same rectangle once
         per tile."""
         self.stats["ready_full"] += 1
+        step = self._full_step(m_p)
+        shp = (m_c.n_banks, m_c.n_steps)
+        return np.broadcast_to(step, shp), np.broadcast_to(False, shp)
+
+    def _full_step(self, m_p: Mapping):
+        """The producer step that finishes its whole output: the digit
+        scan of the full ``[0, dim)`` rectangle, cached per producer
+        mapping. Every ``FullMap`` consumer tile waits for it."""
         step = self._cur.full.get(m_p.cache_key)
         if step is None:
             pl = m_p.layer
@@ -512,8 +525,7 @@ class OverlapEngine:
                 m_p, {d: zero for d in OUTPUT_DIMS},
                 {d: np.full(1, pl.dim(d), dtype=np.int64)
                  for d in OUTPUT_DIMS})[0]
-        shp = (m_c.n_banks, m_c.n_steps)
-        return np.broadcast_to(step, shp), np.broadcast_to(False, shp)
+        return step
 
     # -- batched identity-edge scoring (class histograms) --------------------
 
@@ -837,9 +849,51 @@ class OverlapEngine:
                 vals = vals[keep]
                 cnt = cnt2
             hist.append((vals, cnt[s.jbmap, :]))
+        scores = self._grouped_transform_scores(
+            [cands[k] for k in sel], perfs, tails, hist, objective,
+            blend_alpha)
+        for k, sc in zip(sel, scores):
+            res[k] = sc
+        return res
+
+    def _score_full_batch(self, i: int, cands: Sequence[Mapping],
+                          edges: Sequence[Sequence[Edge]],
+                          done: Dict[int, LayerResult], has_consumer: bool,
+                          objective: str, blend_alpha: float) -> List:
+        """Transform-mode scores for a layer whose edges are all
+        ``FullMap``: every consumer tile waits for each producer's whole
+        output, so the ready matrix is one constant, computed once from
+        the committed producers in ``ready_matrix``'s operation order. A
+        constant matrix is a single value group holding ``n_steps``
+        spaces per original bank (DESIGN.md Section 6), which
+        ``transform_end_grouped`` schedules exactly."""
+        c = 0.0
+        for e in edges[i]:
+            prod = done[e.producer]
+            fin_step, _, _ = self._prod_ranks(prod)
+            c = np.maximum(c, fin_step[self._full_step(prod.mapping)]
+                           + prod.perf.tile_move_ns)
+        perfs = [self.perf(m) for m in cands]
+        tails = ([self.tail(m) for m in cands] if has_consumer
+                 else [0.0] * len(cands))
+        hist = [(np.full(1, c), np.full((m.n_banks, 1), m.n_steps,
+                                        dtype=np.int64)) for m in cands]
+        return self._grouped_transform_scores(cands, perfs, tails, hist,
+                                              objective, blend_alpha)
+
+    def _grouped_transform_scores(self, cands: Sequence[Mapping],
+                                  perfs: Sequence[LayerPerf],
+                                  tails: Sequence[float], hist: List,
+                                  objective: str,
+                                  blend_alpha: float) -> List[float]:
+        """Transform-mode scores from grouped ready histograms: one
+        batched ``transform_end_grouped`` call per distinct bank count.
+        ``hist[j]`` is candidate ``j``'s ascending distinct ready values
+        and their (n_banks, V) per-original-bank counts."""
+        res: List = [None] * len(cands)
         by_nb: Dict[int, List[int]] = {}
-        for j, k in enumerate(sel):
-            by_nb.setdefault(cands[k].n_banks, []).append(j)
+        for j, m in enumerate(cands):
+            by_nb.setdefault(m.n_banks, []).append(j)
         for nb, grp in by_nb.items():
             Vmax = max(hist[j][0].size for j in grp)
             values = np.zeros((len(grp), Vmax))
@@ -850,15 +904,14 @@ class OverlapEngine:
                 counts[x, :v.size, :] = c.T
             ends, moved = transform_end_grouped(
                 values, counts,
-                np.array([cands[sel[j]].n_steps for j in grp]),
+                np.array([cands[j].n_steps for j in grp]),
                 np.array([perfs[j].step_ns for j in grp]),
                 np.array([perfs[j].tile_move_ns for j in grp]))
             for x, j in enumerate(grp):
-                k = sel[j]
                 perf = perfs[j]
                 penalty = tails[j] * perf.compute_ns
                 moved_bytes = int(moved[x]) * float(perf.tile_bytes)
-                res[k] = combine_objective(
+                res[j] = combine_objective(
                     objective,
                     float(ends[x]) + perf.output_move_ns + penalty,
                     perf.energy_pj + moved_bytes * perf.move_pj_per_byte,
@@ -1098,17 +1151,28 @@ class OverlapEngine:
         if has_consumer:
             self._tails_batch(sub)
         # fast path: identity edges with one shared coordinate map score
-        # through the class-histogram batch; anything else (non-identity
-        # maps, mixed pooling, guard overflows) falls back per candidate
+        # through the class-histogram batch; all-FullMap layers in
+        # transform mode through one ready constant; anything else
+        # (other maps, mixed edge kinds or pooling, guard overflows) falls
+        # back per candidate
         fast = (bool(edges[i]) and mode in ("overlap", "transform")
                 and all(type(e.cmap) is IdentityMap for e in edges[i])
                 and len({e.cmap.key() for e in edges[i]}) == 1)
+        full = (bool(edges[i]) and mode == "transform"
+                and all(type(e.cmap) is FullMap for e in edges[i]))
         if fast:
             t0 = time.perf_counter()
             scored = self._score_identity_batch(i, sub, edges, done, mode,
                                                 has_consumer, objective,
                                                 blend_alpha)
             self.times["score_batch_s"] += time.perf_counter() - t0
+        elif full:
+            t0 = time.perf_counter()
+            scored = self._score_full_batch(i, sub, edges, done,
+                                            has_consumer, objective,
+                                            blend_alpha)
+            self.stats["full_scored"] += len(sub)
+            self.times["score_full_s"] += time.perf_counter() - t0
         else:
             scored = [None] * len(sub)
         # the dense stage: the generic ready-step pass and the
@@ -1116,7 +1180,7 @@ class OverlapEngine:
         # some candidate of this call was scored densely
         n_dense = self.stats["dense_scored"]
         t0 = time.perf_counter()
-        if edges[i] and not fast:
+        if edges[i] and not (fast or full):
             for e in edges[i]:
                 self.ready_steps_batch(done[e.producer].mapping, sub,
                                        e.cmap)

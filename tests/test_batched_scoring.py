@@ -11,12 +11,12 @@ import random
 import numpy as np
 import pytest
 
-from repro.core import SearchConfig, chain_edges, describe, dram_pim, \
-    optimize_network
+from repro.core import Edge, FullMap, SearchConfig, chain_edges, describe, \
+    dram_pim, optimize_network
 from repro.core.dataspace import (rect_bounds, rect_bounds_separable,
                                   rect_bounds_separable_stacked,
                                   rect_bounds_stacked)
-from repro.core.engine import OverlapEngine
+from repro.core.engine import OverlapEngine, optimize_network_engine
 from repro.core.overlap import stream_tail_fraction, stream_tail_fractions
 from repro.core.search import LayerSpec, _consumers_of, _score_forward, \
     candidates
@@ -214,6 +214,75 @@ def test_e2e_engine_matches_reference(mode):
                          SearchConfig(n_candidates=8, seed=4,
                                       max_steps=1024, mode=mode,
                                       refine_passes=1, use_engine=False))
+    assert a.total_ns == b.total_ns
+    assert [la.mapping.cache_key for la in a.layers] == \
+        [lb.mapping.cache_key for lb in b.layers]
+
+
+# ---------------------------------------------------------------------------
+# all-FullMap layers: the closed-form pool scorer vs the dense paths
+# ---------------------------------------------------------------------------
+
+def _full_map_net(n_edges):
+    """Two committed producers and a consumer whose edges are all
+    ``FullMap``: from l1 alone, or from l0 and l1 (the ready constant is
+    then the larger of the two producers')."""
+    layers = [LayerSpec("l0", K=8, C=4, P=8, Q=8, R=3, S=3, pad=1),
+              LayerSpec("l1", K=8, C=8, P=8, Q=8, R=3, S=3, pad=1),
+              LayerSpec("l2", K=16, C=8, P=4, Q=4, R=3, S=3, stride=2,
+                        pad=1)]
+    full = [Edge(1, FullMap())] if n_edges == 1 else \
+        [Edge(0, FullMap()), Edge(1, FullMap())]
+    return layers, [[], [Edge(0)], full]
+
+
+@pytest.mark.parametrize("objective", ["latency", "energy", "edp", "blend"])
+@pytest.mark.parametrize("n_edges", [1, 2])
+@pytest.mark.parametrize("seed", range(4))
+def test_full_map_pool_scores_match_dense(seed, n_edges, objective):
+    """A transform-mode pool on an all-FullMap layer is scored from one
+    ready constant, never densely, and every score equals the engine's
+    dense ``_score_forward_one`` and the reference ``_score_forward`` bit
+    for bit — over pools that mix bank counts, with and without a
+    consumer."""
+    layers, edges = _full_map_net(n_edges)
+    arch = _arch()
+    cfg = SearchConfig(n_candidates=12, seed=seed, max_steps=512,
+                       mode="transform", objective=objective,
+                       use_engine=False)
+    res = optimize_network(layers, edges, arch, cfg)
+    done = {i: lr for i, lr in enumerate(res.layers)}
+    pool = candidates(layers[2], arch, cfg, salt=2)
+    assert len({m.n_banks for m in pool}) > 1
+    for has_cons in (True, False):
+        eng = OverlapEngine()
+        got = eng.score_forward_batch(2, pool, edges, done, "transform",
+                                      has_cons, objective)
+        assert eng.stats["full_scored"] == len(pool)
+        assert eng.stats["dense_scored"] == 0
+        dense = [OverlapEngine()._score_forward_one(
+            2, m, edges, done, "transform", has_cons, objective, 0.5)
+            for m in pool]
+        want = [_score_forward(2, m, edges, done, "transform", has_cons,
+                               objective) for m in pool]
+        assert list(got) == dense == want
+
+
+@pytest.mark.parametrize("objective", ["latency", "energy", "edp", "blend"])
+def test_e2e_engine_matches_reference_moe_decode(objective):
+    """Whole transform-mode searches of a small MoE decode scenario,
+    whose expert, router and KV-append layers take the closed-form
+    scorer, choose the reference's mappings and total."""
+    desc = describe("granite_moe_1b_a400m_smoke:decode@64x2")
+    arch = _arch()
+    kw = dict(n_candidates=6, seed=7, max_steps=512, mode="transform",
+              objective=objective)
+    eng = OverlapEngine()
+    a = optimize_network_engine(desc.layers, desc.edges, arch,
+                                SearchConfig(**kw), engine=eng)
+    b = optimize_network(desc.layers, desc.edges, arch,
+                         SearchConfig(use_engine=False, **kw))
+    assert eng.stats["full_scored"] > 0
     assert a.total_ns == b.total_ns
     assert [la.mapping.cache_key for la in a.layers] == \
         [lb.mapping.cache_key for lb in b.layers]

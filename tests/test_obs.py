@@ -475,19 +475,25 @@ def test_publish_metrics_publishes_deltas_once():
 
 
 def _mixed_net():
-    """An identity edge (l0 -> l1, the batched scorer) and a FullMap
-    edge (l1 -> l2, the dense fallback)."""
-    layers = _conv_chain()
-    edges = [[], [Edge(0)], [Edge(1, FullMap())]]
+    """An identity edge (l0 -> l1, the batched scorer), a FullMap edge
+    (l1 -> l2: the closed form in transform mode, the dense fallback in
+    overlap mode) and an identity edge beside a FullMap edge (l1, l2 ->
+    l3, the dense fallback in both)."""
+    layers = _conv_chain() + [
+        LayerSpec("l3", K=8, C=8, P=8, Q=8, R=3, S=3, pad=1)]
+    edges = [[], [Edge(0)], [Edge(1, FullMap())],
+             [Edge(1), Edge(2, FullMap())]]
     return layers, edges
 
 
 @pytest.mark.parametrize("mode", ["overlap", "transform"])
 def test_full_map_ready_steps_take_the_closed_form(mode):
-    """The FullMap layer's ready steps come from the producer alone:
-    ``ready_full`` grows, no consumer tile is projected, the scores are
-    the reference's, and the count is published as
-    ``engine.ready_full``. An identity-only chain never takes it."""
+    """The FullMap layer's ready steps come from the producer alone: in
+    overlap mode ``ready_full`` grows per candidate, in transform mode
+    the whole pool is scored from one ready constant (``full_scored``);
+    either way no consumer tile is projected, the scores are the
+    reference's, and the count is published under ``engine.``. An
+    identity-only chain never takes it."""
     layers, edges = _mixed_net()
     arch = _small_arch()
     cfg = SearchConfig(n_candidates=8, seed=0, max_steps=512, mode=mode,
@@ -501,14 +507,18 @@ def test_full_map_ready_steps_take_the_closed_form(mode):
     proj_miss = eng.stats["proj_miss"]
     pool = candidates(layers[2], arch, cfg, salt=2)
     got = eng.score_forward_batch(2, pool, edges, done, mode, False)
-    assert eng.stats["ready_full"] == len({m.cache_key for m in pool})
+    if mode == "overlap":
+        assert eng.stats["ready_full"] == len({m.cache_key for m in pool})
+        key = "ready_full"
+    else:
+        assert eng.stats["full_scored"] == len(pool)
+        key = "full_scored"
     assert eng.stats["proj_miss"] == proj_miss
     assert list(got) == [_score_forward(2, m, edges, done, mode, False)
                          for m in pool]
     reg = Registry()
     eng.publish_metrics(registry=reg)
-    assert reg.snapshot()["counters"]["engine.ready_full"] \
-        == eng.stats["ready_full"]
+    assert reg.snapshot()["counters"]["engine." + key] == eng.stats[key]
 
     ident = OverlapEngine()
     chain = _conv_chain()
@@ -546,10 +556,44 @@ def test_ready_cmap_counts_generic_map_ready_matrices(network, head_folds):
     assert got.get("engine.ready_cmap_s", 0) == eng.times["ready_cmap_s"]
 
 
+@pytest.mark.parametrize("network,full_layers", [
+    ("granite_moe_1b_a400m_smoke:decode@16", True),
+    ("deepseek_v2_smoke_ep2:decode@16x2", True),
+    ("resnet18", False),
+    ("conv_chain", False)])
+def test_full_scored_counts_closed_form_pools(network, full_layers):
+    """``engine.full_scored``/``score_full_s`` count and time the
+    candidates of all-FullMap layers scored in closed form: non-zero on
+    MoE decode networks (expert, router and cache-append layers), zero
+    on identity-only networks, whose first edge already fails the
+    test. The closed-form scores count among ``batch_scored``."""
+    if network == "conv_chain":
+        layers = _conv_chain()
+        edges = chain_edges(layers)
+    else:
+        desc = describe(network)
+        layers, edges = desc.layers, desc.edges
+    eng = OverlapEngine()
+    cfg = SearchConfig(n_candidates=4, seed=0, max_steps=256,
+                       mode="transform")
+    optimize_network_engine(layers, edges, _small_arch(), cfg, engine=eng)
+    assert (eng.stats["full_scored"] > 0) is full_layers
+    assert (eng.times["score_full_s"] > 0) is full_layers
+    assert eng.stats["full_scored"] <= eng.stats["batch_scored"]
+    reg = Registry()
+    eng.publish_metrics(registry=reg)
+    got = reg.snapshot()["counters"]
+    assert got.get("engine.full_scored", 0) == eng.stats["full_scored"]
+    assert got.get("engine.score_full_s", 0) == eng.times["score_full_s"]
+    assert ("engine.full_scored" in got) is full_layers
+
+
 def test_publish_metrics_times_follow_their_counts():
-    """``engine.score_batch_s``/``score_dense_s`` are published once
-    per delta, and each is positive exactly when ``batch_scored``/
-    ``dense_scored`` grew since the last publish."""
+    """``engine.score_batch_s``/``score_full_s``/``score_dense_s`` are
+    published once per delta, and each is positive exactly when its
+    count grew since the last publish: ``batch_scored`` less
+    ``full_scored`` (the closed-form scores count as batched),
+    ``full_scored`` and ``dense_scored``."""
     layers, edges = _mixed_net()
     arch = _small_arch()
     cfg = SearchConfig(n_candidates=8, seed=0, max_steps=512,
@@ -562,18 +606,24 @@ def test_publish_metrics_times_follow_their_counts():
     def score_and_publish(i):
         before = reg.snapshot()["counters"]
         eng.score_forward_batch(i, candidates(layers[i], arch, cfg, salt=i),
-                                edges, done, "transform", i < 2)
+                                edges, done, "transform", i < 3)
         eng.publish_metrics(registry=reg)
         after = reg.snapshot()["counters"]
         return {k: after.get(k, 0) - before.get(k, 0) for k in after}
 
-    for i, batch, dense in ((1, True, False), (2, False, True)):
+    for i, batch, full, dense in ((1, True, False, False),
+                                  (2, False, True, False),
+                                  (3, False, False, True)):
         d = score_and_publish(i)
-        assert (d.get("engine.batch_scored", 0) > 0) is batch
+        assert (d.get("engine.batch_scored", 0)
+                - d.get("engine.full_scored", 0) > 0) is batch
         assert (d.get("engine.score_batch_s", 0) > 0) is batch
+        assert (d.get("engine.full_scored", 0) > 0) is full
+        assert (d.get("engine.score_full_s", 0) > 0) is full
         assert (d.get("engine.dense_scored", 0) > 0) is dense
         assert (d.get("engine.score_dense_s", 0) > 0) is dense
-    assert eng.times["score_batch_s"] > 0 and eng.times["score_dense_s"] > 0
+    assert all(eng.times[k] > 0 for k in ("score_batch_s", "score_full_s",
+                                          "score_dense_s"))
     # the times stay out of the integer stats the service diffs
     assert all(isinstance(v, int) for v in eng.stats.values())
     first = reg.snapshot()["counters"]
@@ -614,6 +664,7 @@ def test_search_stage_spans_and_timers_fit_inside_the_layers(tmp_path):
     stages = (sum(e["dur_s"] for e in spans["search.candidates"])
               + sum(e["dur_s"] for e in commits)
               + counters["engine.score_batch_s"]
+              + counters["engine.score_full_s"]
               + counters["engine.score_dense_s"])
     assert 0 < stages <= sum(e["dur_s"] for e in spans["search.layer"]) \
         + closing
