@@ -1,18 +1,18 @@
 """Batched-scorer differential smoke: engine vs reference byte-equality.
 
-Runs ``optimize_network`` twice per configuration — once through the
-batched ``OverlapEngine`` and once through the scalar reference path
-(``use_engine=False``) — over a small strategy x mode x objective matrix
-on resnet18, and fails (exit 1) on any divergence in ``total_ns`` or the
-chosen mappings. This is the CI-sized version of the bit-identity
-contract (DESIGN.md §6); the full differential suite lives in
-``tests/test_batched_scoring.py``.
+Runs each configuration twice — once through the batched
+``OverlapEngine`` and once through the scalar reference path
+(``optimize_network_reference``) — over a small strategy x mode x
+objective matrix on resnet18, and fails (exit 1) on any divergence in
+``total_ns`` or the chosen mappings. This is the CI-sized version of
+the bit-identity contract (DESIGN.md §6); the full differential suite
+lives in ``tests/test_batched_scoring.py``.
 """
 import sys
 import time
 
 from repro.core import SearchConfig, describe, dram_pim
-from repro.core.search import _optimize_network_reference
+from repro.core.search import optimize_network_reference
 from repro.core.engine import OverlapEngine, optimize_network_engine
 
 MATRIX = [
@@ -32,8 +32,8 @@ def main() -> int:
                            objective=objective, n_candidates=4, seed=7,
                            max_steps=1024)
         t0 = time.perf_counter()
-        ref = _optimize_network_reference(desc.layers, desc.edges, arch,
-                                          cfg)
+        ref = optimize_network_reference(desc.layers, desc.edges, arch,
+                                         cfg)
         t1 = time.perf_counter()
         got = optimize_network_engine(desc.layers, desc.edges, arch, cfg,
                                       engine=OverlapEngine())

@@ -13,9 +13,10 @@ Entry points::
 
 All also work as ``python -m benchmarks.run`` with ``PYTHONPATH=src``;
 run as a plain script the repo root and ``src/`` are bootstrapped onto
-``sys.path``. The ``bench`` suite prints ``name,us_per_call,derived`` CSV
-(set ``BENCH_FULL=1`` for paper-scale budgets); perf-relevant rows are
-mirrored into ``BENCH_search.json``. The ``dse`` subcommand co-searches
+``sys.path``. The ``bench`` suite prints the paper figures as
+``name,us_per_call,derived`` CSV (set ``BENCH_FULL=1`` for paper-scale
+budgets) and writes no file; speed is measured on the chip by the
+benchmark in ``bench/``. The ``dse`` subcommand co-searches
 PIM architectures x overlap mappings (``repro.dse``), prints the Pareto
 frontier and writes a resumable JSONL journal — re-running a finished
 sweep performs zero new mapping searches. ``dse --distributed N`` runs
@@ -228,8 +229,6 @@ def bench_main(argv=()) -> None:
     args = _obs_flags(argparse.ArgumentParser(
         prog="run.py bench",
         description="Paper-figure CSV suite.")).parse_args(argv)
-    from repro.launch.compile_cache import enable_compile_cache
-    enable_compile_cache()
     finish_obs = _setup_obs(args)
     try:
         _bench_suite()
@@ -239,17 +238,9 @@ def bench_main(argv=()) -> None:
 
 def _bench_suite() -> None:
     # one function per paper table/figure
-    from benchmarks import (bench_kernels, bench_search, bench_serve,
-                            paper_figs)
+    from benchmarks import paper_figs
 
     benches = [
-        bench_search.scoring_throughput,
-        bench_search.obs_overhead,
-        bench_search.e2e_speedup,
-        bench_search.search_wall,
-        bench_search.objective_frontier,
-        bench_search.worker_scaling,
-        bench_serve.serve_latency,
         paper_figs.fig4_motivation,
         paper_figs.fig10_overall,
         paper_figs.fig11_vs_overlapim,
@@ -260,7 +251,6 @@ def _bench_suite() -> None:
         paper_figs.fig16_reram,
         paper_figs.fig17_bert,
         paper_figs.sec4f_dataspace_generation,
-        bench_kernels.kernels,
     ]
     print("name,us_per_call,derived")
     t0 = time.time()
@@ -368,26 +358,17 @@ def _write_frontier(res, path) -> None:
 
 def dse_main(argv) -> None:
     args = _dse_parser().parse_args(argv)
-    from benchmarks import record
     from repro.dse import (best_arch_table, execute_sweep, frontier_table,
-                           journal_template, network_token, objective_tag,
-                           shared_dir_for, summarize, sweep_networks,
-                           sweep_summary)
+                           journal_template, network_token, shared_dir_for,
+                           summarize, sweep_networks)
 
     # one journal-naming scheme for both branches (repro.dse.driver —
     # shared with the mapping service); a literal --journal path has no
     # {placeholders} and formats to itself
-    obj_tag = objective_tag(args.objective, args.blend_alpha)
     template = args.journal or journal_template(
         args.family, args.objective, args.blend_alpha)
 
     base = _dse_config_from_args(args)
-
-    # dse-journal key: objective-suffixed for non-latency sweeps so the
-    # pre-energy entries keep tracking the latency trajectory
-    def dse_key(net, mode) -> str:
-        return f"{args.family}/{net}/{mode}" + (
-            f"/{obj_tag}" if obj_tag else "")
 
     if args.network == "all":
         if args.distributed or args.compact_journal or args.frontier_out:
@@ -401,7 +382,6 @@ def dse_main(argv) -> None:
             print(summarize(res))
             print(frontier_table(res.frontier))
             print()
-            record.update_dse(dse_key(net, mode), sweep_summary(res))
         print(best_arch_table(results))
         return
 
@@ -437,8 +417,6 @@ def dse_main(argv) -> None:
     else:
         print(f"dse: journal={cfg.journal_path} entries={_journal_len(cfg)}")
     _write_frontier(res, args.frontier_out)
-    record.update_dse(dse_key(args.network, args.mode),
-                      sweep_summary(res))
 
 
 def _journal_len(cfg) -> int:

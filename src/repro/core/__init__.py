@@ -16,7 +16,8 @@ from .perf_model import (LayerPerf, PerfCache, analyze, arch_area_proxy,
                          arch_power_proxy, move_energy_pj, step_latency_ns)
 from .search import (MODES, OBJECTIVES, STRATEGIES, LayerResult,
                      NetworkResult, SearchConfig, combine_objective,
-                     evaluate_chain, optimize_network)
+                     evaluate_chain, optimize_network,
+                     optimize_network_reference)
 from .transform import TransformResult, transform_schedule
 from .workload import (DIMS, OUTPUT_DIMS, REDUCTION_DIMS, LayerSpec,
                        bert_encoder, conv, get_network, matmul, resnet18,

@@ -1293,11 +1293,6 @@ def optimize_network_engine(layers: Sequence[LayerSpec],
     """Engine-backed ``optimize_network``: identical algorithm, candidates
     and tie-breaking as the reference path — same chosen mappings, same
     ``total_ns`` — with batched scoring and incremental refinement."""
-    if cfg.use_exhaustive_overlap:
-        raise ValueError(
-            "use_exhaustive_overlap has no engine twin; call "
-            "optimize_network, which routes the flag to the reference "
-            "implementation")
     eng = engine or OverlapEngine()
     n = len(layers)
     order, backward_part = _visit_order(layers, cfg.strategy)

@@ -67,14 +67,10 @@ class SearchConfig:
     max_steps: int = 16384
     mode: str = "transform"
     strategy: str = "forward"
-    use_exhaustive_overlap: bool = False  # OverlaPIM's analysis (slow)
     # beyond-paper: coordinate-descent passes re-optimizing each layer
     # against both committed neighbors (0 = the paper's linear search)
     refine_passes: int = 0
     refine_candidates: int = 8
-    # batched/memoizing engine (core.engine); False = per-candidate
-    # reference path, kept as the differential-test oracle
-    use_engine: bool = True
     # scoring objective ("latency" reproduces the paper exactly);
     # blend_alpha is the energy weight of the "blend" objective
     objective: str = "latency"
@@ -156,8 +152,8 @@ def _ready_matrix(idx: int, mapping: Mapping, edges: Sequence[Edge],
     dependency edges (paper Section IV-G: latest producing space).
 
     ``use_exhaustive`` switches the ready-step analysis to OverlaPIM's
-    O(N*M) traversal (``SearchConfig.use_exhaustive_overlap``) — the
-    baseline the paper compares against. Result-identical to the
+    O(N*M) traversal (``optimize_network_reference(exhaustive=True)``),
+    the baseline the paper compares against. Result-identical to the
     analytical path (property-tested), just slow."""
     nb, nt = mapping.n_banks, mapping.n_steps
     ready = np.zeros((nb, nt), dtype=np.float64)
@@ -178,7 +174,7 @@ def _ready_matrix(idx: int, mapping: Mapping, edges: Sequence[Edge],
 def evaluate_chain(mappings: Sequence[Mapping],
                    edges: Sequence[Sequence[Edge]],
                    mode: str,
-                   use_exhaustive_overlap: bool = False) -> NetworkResult:
+                   exhaustive: bool = False) -> NetworkResult:
     """Run the whole network with fixed mappings under a given mode."""
     done: Dict[int, LayerResult] = {}
     per_layer = []
@@ -194,8 +190,7 @@ def evaluate_chain(mappings: Sequence[Mapping],
             end = start + perf.compute_ns + perf.output_move_ns
             res = LayerResult(m, perf, start, end, fin)
         else:
-            ready = _ready_matrix(i, m, edges[i], done,
-                                  use_exhaustive_overlap)
+            ready = _ready_matrix(i, m, edges[i], done, exhaustive)
             start = float(ready.min()) if ready.size else 0.0
             if mode == "transform" and edges[i]:
                 tr = transform_schedule(
@@ -338,28 +333,29 @@ def optimize_network(layers: Sequence[LayerSpec],
                      edges: Sequence[Sequence[Edge]],
                      arch: ArchSpec,
                      cfg: Optional[SearchConfig] = None) -> NetworkResult:
+    """Best mapping per layer, scored by the batched engine
+    (``core.engine``); bit-identical to ``optimize_network_reference``."""
     cfg = cfg or SearchConfig()
     with obs.span("search.optimize", n_layers=len(layers), mode=cfg.mode,
-                  strategy=cfg.strategy, objective=cfg.objective,
-                  engine=cfg.use_engine
-                  and not cfg.use_exhaustive_overlap):
-        # the OverlaPIM-baseline analysis has no batched engine twin:
-        # fall back to the reference path (the engine itself raises if
-        # handed the flag directly)
-        if cfg.use_engine and not cfg.use_exhaustive_overlap:
-            from .engine import optimize_network_engine  # lazy: no cycle
-            return optimize_network_engine(layers, edges, arch, cfg)
-        return _optimize_network_reference(layers, edges, arch, cfg)
+                  strategy=cfg.strategy, objective=cfg.objective):
+        from .engine import optimize_network_engine  # lazy: no cycle
+        return optimize_network_engine(layers, edges, arch, cfg)
 
 
-def _optimize_network_reference(layers: Sequence[LayerSpec],
-                                edges: Sequence[Sequence[Edge]],
-                                arch: ArchSpec,
-                                cfg: SearchConfig) -> NetworkResult:
-    """Pre-engine per-candidate path — the differential-test oracle."""
+def optimize_network_reference(layers: Sequence[LayerSpec],
+                               edges: Sequence[Sequence[Edge]],
+                               arch: ArchSpec,
+                               cfg: SearchConfig,
+                               exhaustive: bool = False) -> NetworkResult:
+    """The per-candidate search the engine must reproduce: same
+    candidates, tie-breaking, chosen mappings and ``total_ns`` as
+    ``optimize_network``, one candidate scored at a time. It is the
+    differential-test oracle. ``exhaustive=True`` computes ready steps
+    with OverlaPIM's O(N*M) traversal (``ready_steps_exhaustive``), the
+    baseline the paper compares against; results are identical, only
+    slower."""
     n = len(layers)
     order, backward_part = _visit_order(layers, cfg.strategy)
-    exh = cfg.use_exhaustive_overlap
 
     chosen: Dict[int, Mapping] = {}
     done: Dict[int, LayerResult] = {}
@@ -371,7 +367,7 @@ def _optimize_network_reference(layers: Sequence[LayerSpec],
                                                      cfg.mode,
                                                      cfg.objective,
                                                      cfg.blend_alpha,
-                                                     exh))
+                                                     exhaustive))
         else:
             # forward scoring needs producers committed; producers missing
             # (backward half not yet visited) fall back to sequential score
@@ -380,7 +376,7 @@ def _optimize_network_reference(layers: Sequence[LayerSpec],
             if avail:
                 best = min(cands, key=lambda m: _score_forward(
                     i, m, edges, done, cfg.mode, has_cons,
-                    cfg.objective, cfg.blend_alpha, exh))
+                    cfg.objective, cfg.blend_alpha, exhaustive))
             else:
                 def _seq_score(m):
                     p = analyze(m)
@@ -390,9 +386,9 @@ def _optimize_network_reference(layers: Sequence[LayerSpec],
                 best = min(cands, key=_seq_score)
         chosen[i] = best
         if all(e.producer in done for e in edges[i]):
-            done[i] = _commit(i, best, edges, done, cfg.mode, exh)
+            done[i] = _commit(i, best, edges, done, cfg.mode, exhaustive)
     result = evaluate_chain([chosen[i] for i in range(n)], edges,
-                            cfg.mode, exh)
+                            cfg.mode, exhaustive)
     # coordinate-descent refinement (beyond-paper): re-optimize each layer
     # against BOTH its committed producer and consumer — the paper's
     # linear pass is myopic about successors (Section IV-K motivates this)
@@ -409,7 +405,7 @@ def _optimize_network_reference(layers: Sequence[LayerSpec],
                 trial = chosen.copy()
                 trial[i] = m
                 r = evaluate_chain([trial[j] for j in range(n)], edges,
-                                   cfg.mode, exh)
+                                   cfg.mode, exhaustive)
                 sc = r.objective_value(cfg.objective, cfg.blend_alpha)
                 if sc < best_t - 1e-9:
                     best_m, best_t = m, sc
@@ -417,7 +413,7 @@ def _optimize_network_reference(layers: Sequence[LayerSpec],
                 chosen[i] = best_m
                 improved = True
         result = evaluate_chain([chosen[i] for i in range(n)], edges,
-                                cfg.mode, exh)
+                                cfg.mode, exhaustive)
         if not improved:
             break
     result.objective = cfg.objective

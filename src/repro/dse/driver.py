@@ -28,11 +28,11 @@ JOURNAL_ROOT = "dse_runs"
 
 
 def objective_tag(objective: str, blend_alpha: float = 0.5) -> str:
-    """Filename/BENCH-key token of a sweep objective.
+    """Journal-filename token of a sweep objective.
 
     Empty for ``latency`` (the implicit objective of every pre-energy
     journal, so their paths stay stable); ``blend`` carries its alpha so
-    differently-weighted sweeps never share a journal or a BENCH entry.
+    differently-weighted sweeps never share a journal.
     """
     if objective == "latency":
         return ""
@@ -121,7 +121,7 @@ def execute_sweep(cfg: DSEConfig, *,
 
 def sweep_summary(res: DSEResult) -> Dict:
     """Machine-readable summary of one sweep — THE schema behind
-    ``BENCH_search.json["dse"]`` entries and service responses: stats,
+    service responses (``MappingResponse.sweep_summary``): stats,
     baseline, iso-area and EDP winners, and the full frontier with the
     EDP-dominance flag against the latency-only baseline."""
     best = res.best_within_area() or res.baseline
@@ -152,7 +152,7 @@ def sweep_summary(res: DSEResult) -> Dict:
             p.objectives[0] * p.objectives[1] < record_edp(res.baseline)
             for p in res.frontier.points),
         # the energy-aware frontier itself (latency/energy/area all
-        # minimized), so BENCH_search.json records the trade-off
+        # minimized), so a response carries the trade-off
         "frontier_points": frontier_points(res),
     }
 

@@ -90,12 +90,12 @@ class DSEConfig:
         assert self.budget >= 1, "budget must be >= 1"
 
     def search_config(self) -> SearchConfig:
-        """The per-point mapping-search config (always engine-backed)."""
+        """The per-point mapping-search config."""
         return SearchConfig(n_candidates=self.n_candidates, seed=self.seed,
                             max_steps=self.max_steps, mode=self.mode,
                             strategy=self.strategy,
                             refine_passes=self.refine_passes,
-                            use_engine=True, objective=self.objective,
+                            objective=self.objective,
                             blend_alpha=self.blend_alpha)
 
     def objective_token(self) -> str:
@@ -154,7 +154,7 @@ def point_key(space: ParamSpace, point: DesignPoint,
 
 def record_edp(rec: Dict) -> float:
     """THE energy-delay product of an evaluation record — every report
-    and BENCH entry goes through here. Pre-energy journal records lack
+    and sweep summary goes through here. Pre-energy journal records lack
     the ``edp_ns_pj`` column; it is recomputed from what they do carry."""
     if "edp_ns_pj" in rec:
         return rec["edp_ns_pj"]

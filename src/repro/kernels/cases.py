@@ -1,9 +1,8 @@
 """One realistic shape per Pallas kernel, with its oracle and error bound.
 
 Shared by ``chip_smoke.py`` (runs each compiled kernel on the chip against
-its oracle), ``benchmarks/bench_kernels.py`` (times them) and
-``tests/test_chip_compile.py`` (compiles them for a described v5e), so the
-three always mean the same shapes.
+its oracle) and ``tests/test_chip_compile.py`` (compiles them for a
+described v5e), so the two always mean the same shapes.
 
 Each bound is on ``max|kernel - oracle| / max|oracle|``, with the oracle
 evaluated at ``highest`` matmul precision. bf16 keeps 8 significant bits

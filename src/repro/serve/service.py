@@ -666,7 +666,7 @@ class MappingService:
                                        {"key": best["key"],
                                         "mapping": mapping})
         # the frontier is carried once, top-level; the summary keeps
-        # every other sweep_summary column (the BENCH-compatible shape)
+        # every other sweep_summary column
         summary = dict(sweep_summary(res))
         pts = summary.pop("frontier_points")
         return MappingResponse(

@@ -1,7 +1,7 @@
 """Differential tests for the batched candidate scorer (DESIGN.md §6).
 
 The reference per-candidate path (``search._score_forward``,
-``use_engine=False``) is the oracle: every batched score must be
+``optimize_network_reference``) is the oracle: every batched score must be
 *bit-identical* to it — the batch restructuring only reorders exact
 integer/float operations that are reassociation-safe (see DESIGN.md §6
 for the argument per stage).
@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from repro.core import Edge, FullMap, SearchConfig, chain_edges, describe, \
-    dram_pim, optimize_network
+    dram_pim, optimize_network, optimize_network_reference
 from repro.core.dataspace import (rect_bounds, rect_bounds_separable,
                                   rect_bounds_separable_stacked,
                                   rect_bounds_stacked)
@@ -210,10 +210,7 @@ def test_e2e_engine_matches_reference(mode):
     cfg = SearchConfig(n_candidates=8, seed=4, max_steps=1024, mode=mode,
                        refine_passes=1)
     a = optimize_network(net, edges, arch, cfg)
-    b = optimize_network(net, edges, arch,
-                         SearchConfig(n_candidates=8, seed=4,
-                                      max_steps=1024, mode=mode,
-                                      refine_passes=1, use_engine=False))
+    b = optimize_network_reference(net, edges, arch, cfg)
     assert a.total_ns == b.total_ns
     assert [la.mapping.cache_key for la in a.layers] == \
         [lb.mapping.cache_key for lb in b.layers]
@@ -248,9 +245,8 @@ def test_full_map_pool_scores_match_dense(seed, n_edges, objective):
     layers, edges = _full_map_net(n_edges)
     arch = _arch()
     cfg = SearchConfig(n_candidates=12, seed=seed, max_steps=512,
-                       mode="transform", objective=objective,
-                       use_engine=False)
-    res = optimize_network(layers, edges, arch, cfg)
+                       mode="transform", objective=objective)
+    res = optimize_network_reference(layers, edges, arch, cfg)
     done = {i: lr for i, lr in enumerate(res.layers)}
     pool = candidates(layers[2], arch, cfg, salt=2)
     assert len({m.n_banks for m in pool}) > 1
@@ -280,8 +276,8 @@ def test_e2e_engine_matches_reference_moe_decode(objective):
     eng = OverlapEngine()
     a = optimize_network_engine(desc.layers, desc.edges, arch,
                                 SearchConfig(**kw), engine=eng)
-    b = optimize_network(desc.layers, desc.edges, arch,
-                         SearchConfig(use_engine=False, **kw))
+    b = optimize_network_reference(desc.layers, desc.edges, arch,
+                                   SearchConfig(**kw))
     assert eng.stats["full_scored"] > 0
     assert a.total_ns == b.total_ns
     assert [la.mapping.cache_key for la in a.layers] == \

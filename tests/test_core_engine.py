@@ -18,8 +18,9 @@ from repro.core import (Edge, FullMap, IdentityMap, LayerSpec,
                         ready_steps_exhaustive)
 from repro.core.engine import (OverlapEngine, max_step_in_rect_dedup,
                                optimize_network_engine)
-from repro.core.search import (_consumers_of, _optimize_network_reference,
-                               _score_backward, _score_forward, candidates)
+from repro.core.search import (_consumers_of, _score_backward,
+                               _score_forward, candidates,
+                               optimize_network_reference)
 from repro.core.transform import transform_schedule
 
 
@@ -176,7 +177,7 @@ def test_score_forward_batch_matches_reference(mode):
     edges = chain_edges(net)
     arch = small_arch()
     c = cfg(mode=mode)
-    ref = _optimize_network_reference(net, edges, arch, c)
+    ref = optimize_network_reference(net, edges, arch, c)
     done = {i: lr for i, lr in enumerate(ref.layers)}
     eng = OverlapEngine()
     for i in range(len(net)):
@@ -249,7 +250,7 @@ def test_transform_schedule_precomputed_order():
 
 def _assert_search_equal(layers, edges, arch, c):
     a = optimize_network_engine(layers, edges, arch, c)
-    b = _optimize_network_reference(layers, edges, arch, c)
+    b = optimize_network_reference(layers, edges, arch, c)
     assert a.total_ns == b.total_ns
     assert a.per_layer_ns == pytest.approx(b.per_layer_ns, abs=0)
     for x, y in zip(a.layers, b.layers):
@@ -301,7 +302,7 @@ def test_engine_reuse_across_archs_keyed_bundles():
     for arch in (arch_a, arch_b, arch_a):
         c = cfg(mode="transform")
         got = optimize_network_engine(net, edges, arch, c, engine=eng)
-        ref = _optimize_network_reference(net, edges, arch, c)
+        ref = optimize_network_reference(net, edges, arch, c)
         assert got.total_ns == ref.total_ns
         # backward scoring path too (shares the score/ready caches)
         fixed = {2: candidates(net[2], arch, c, salt=2)[0]}
@@ -330,7 +331,7 @@ def test_engine_evict_arch():
     assert eng.evict_arch(arch_a.to_key()) # by key string
     assert eng.n_arch_bundles == 0
     got = optimize_network_engine(net, edges, arch_b, c, engine=eng)
-    ref = _optimize_network_reference(net, edges, arch_b, c)
+    ref = optimize_network_reference(net, edges, arch_b, c)
     assert got.total_ns == ref.total_ns
 
 
@@ -354,7 +355,7 @@ def test_evict_arch_does_not_clobber_other_bundles():
     got = optimize_network_engine(net, edges, arch_b, c, engine=eng)
     assert eng._bundles[arch_b.to_key()] is bundle_b
     assert len(bundle_b.ready) == n_ready_b  # warm, not recomputed
-    ref = _optimize_network_reference(net, edges, arch_b, c)
+    ref = optimize_network_reference(net, edges, arch_b, c)
     assert got.total_ns == ref.total_ns
 
 
@@ -379,18 +380,15 @@ def test_engine_multi_arch_bundle_retention():
     res = optimize_network_engine(net, edges, arch_a2, c, engine=eng)
     assert eng._bundles[arch_a2.to_key()].ready is ready_a
     assert len(ready_a) == n_ready
-    ref = _optimize_network_reference(net, edges, arch_a, c)
+    ref = optimize_network_reference(net, edges, arch_a, c)
     assert res.total_ns == ref.total_ns
 
 
-def test_use_engine_flag_dispatch():
-    """optimize_network(use_engine=True) is the default and matches the
-    reference path."""
+def test_optimize_network_matches_reference():
+    """``optimize_network`` (the engine) matches the reference path."""
     net = conv_chain()
     edges = chain_edges(net)
     arch = small_arch()
     a = optimize_network(net, edges, arch, cfg(mode="transform"))
-    b = optimize_network(net, edges, arch,
-                         cfg(mode="transform", use_engine=False))
-    assert SearchConfig().use_engine is True
+    b = optimize_network_reference(net, edges, arch, cfg(mode="transform"))
     assert a.total_ns == b.total_ns

@@ -1,12 +1,11 @@
 """Whole-network search: modes, strategies, chain evaluation, BERT edges."""
-import dataclasses
-
 import numpy as np
 import pytest
 
 from repro.core import (LayerSpec, SearchConfig, chain_edges, describe,
                         dram_pim, evaluate_chain, heuristic_mapping,
-                        optimize_network, reram_pim)
+                        optimize_network, optimize_network_reference,
+                        reram_pim)
 
 
 def tiny_arch():
@@ -120,8 +119,9 @@ def test_refinement_never_worse():
     assert ref.total_ns <= base.total_ns + 1e-6
 
 
-def test_use_exhaustive_overlap_changes_code_path(monkeypatch):
-    """SearchConfig.use_exhaustive_overlap routes the reference path's
+@pytest.mark.parametrize("mode", ["overlap", "transform"])
+def test_reference_exhaustive_changes_code_path(monkeypatch, mode):
+    """``optimize_network_reference(exhaustive=True)`` routes the
     ready-step analysis through OverlaPIM's exhaustive traversal (it was
     once declared but never consulted — baseline comparisons silently ran
     the fast path)."""
@@ -143,25 +143,15 @@ def test_use_exhaustive_overlap_changes_code_path(monkeypatch):
     monkeypatch.setattr(search_mod, "ready_steps_analytical", count_ana)
 
     net = tiny_net()
-    small = cfg(n_candidates=3, max_steps=64, mode="overlap")
-    on = optimize_network(net, chain_edges(net), tiny_arch(),
-                          dataclasses.replace(small,
-                                              use_exhaustive_overlap=True))
+    small = cfg(n_candidates=3, max_steps=64, mode=mode)
+    on = optimize_network_reference(net, chain_edges(net), tiny_arch(),
+                                    small, exhaustive=True)
     assert calls["exh"] > 0 and calls["ana"] == 0
 
     calls["exh"] = calls["ana"] = 0
-    off = optimize_network(net, chain_edges(net), tiny_arch(),
-                           dataclasses.replace(small, use_engine=False))
+    off = optimize_network_reference(net, chain_edges(net), tiny_arch(),
+                                     small)
     assert calls["exh"] == 0 and calls["ana"] > 0
     # the exhaustive analysis is the oracle the analytical closed form
-    # reproduces, so both flags pick the same mappings and timings
+    # reproduces, so both pick the same mappings and timings
     assert on.total_ns == off.total_ns
-
-
-def test_engine_rejects_exhaustive_overlap():
-    from repro.core.engine import optimize_network_engine
-
-    net = tiny_net()
-    with pytest.raises(ValueError):
-        optimize_network_engine(net, chain_edges(net), tiny_arch(),
-                                cfg(use_exhaustive_overlap=True))

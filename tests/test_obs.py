@@ -21,7 +21,8 @@ import pytest
 
 from repro import obs
 from repro.core import (Edge, FullMap, LayerSpec, SearchConfig,
-                        chain_edges, describe, dram_pim, optimize_network)
+                        chain_edges, describe, dram_pim, optimize_network,
+                        optimize_network_reference)
 from repro.core.engine import OverlapEngine, optimize_network_engine
 from repro.core.search import _consumers_of, _score_forward, candidates
 from repro.dse import (DSEConfig, DistribConfig, ParamSpace,
@@ -496,10 +497,9 @@ def test_full_map_ready_steps_take_the_closed_form(mode):
     identity-only chain never takes it."""
     layers, edges = _mixed_net()
     arch = _small_arch()
-    cfg = SearchConfig(n_candidates=8, seed=0, max_steps=512, mode=mode,
-                       use_engine=False)
-    done = dict(enumerate(optimize_network(layers, edges, arch,
-                                           cfg).layers))
+    cfg = SearchConfig(n_candidates=8, seed=0, max_steps=512, mode=mode)
+    done = dict(enumerate(optimize_network_reference(layers, edges, arch,
+                                                     cfg).layers))
     eng = OverlapEngine()
     eng.score_forward_batch(1, candidates(layers[1], arch, cfg, salt=1),
                             edges, done, mode)
@@ -720,9 +720,9 @@ def test_sustained_scoring_makes_zero_obs_dispatches(monkeypatch):
 
 def test_sustained_scoring_overhead_is_bounded():
     """Wall-clock half, deliberately loose (a gross-regression tripwire
-    only — ``bench_search.obs_overhead`` tracks the real number): the
-    same sustained pass with telemetry enabled must stay within 2x of
-    disabled."""
+    only — a search's tracing cost is read from the benchmark's traced
+    runs in ``bench/``): the same sustained pass with telemetry enabled
+    must stay within 2x of disabled."""
     import time
 
     eng = OverlapEngine()
@@ -782,11 +782,7 @@ def test_engine_matches_reference_with_tracing_on(tmp_path):
     arch = _small_arch()
     cfg = SearchConfig(n_candidates=8, seed=0, max_steps=512,
                        mode="transform", refine_passes=1)
-    ref = optimize_network(layers, edges, arch,
-                           SearchConfig(n_candidates=8, seed=0,
-                                        max_steps=512, mode="transform",
-                                        refine_passes=1,
-                                        use_engine=False))
+    ref = optimize_network_reference(layers, edges, arch, cfg)
     obs.enable(trace_path=str(tmp_path / "t.jsonl"), sample_every=2)
     traced = optimize_network(layers, edges, arch, cfg)
     obs.disable()

@@ -21,7 +21,7 @@ from repro.core import (LayerSpec, SearchConfig, chain_edges, dram_pim,
                         optimize_network)
 from repro.core.engine import OverlapEngine, optimize_network_engine
 from repro.core.search import (MODES, OBJECTIVES, STRATEGIES,
-                               _optimize_network_reference)
+                               optimize_network_reference)
 
 ENERGY_OBJECTIVES = tuple(o for o in OBJECTIVES if o != "latency")
 
@@ -80,8 +80,8 @@ def test_reference_path_deterministic(strategy):
     net, arch = conv_chain(), small_arch()
     edges = chain_edges(net)
     c = cfg(strategy=strategy)
-    a = _optimize_network_reference(net, edges, arch, c)
-    b = _optimize_network_reference(net, edges, arch, c)
+    a = optimize_network_reference(net, edges, arch, c)
+    b = optimize_network_reference(net, edges, arch, c)
     assert_results_identical(a, b)
 
 
@@ -93,8 +93,7 @@ def test_engine_matches_reference_per_strategy(strategy):
     edges = chain_edges(net)
     c = cfg(strategy=strategy)
     a = optimize_network(net, edges, arch, c)
-    b = optimize_network(net, edges, arch,
-                         dataclasses.replace(c, use_engine=False))
+    b = optimize_network_reference(net, edges, arch, c)
     assert_results_identical(a, b)
 
 
@@ -129,8 +128,7 @@ def test_engine_matches_reference_per_objective(strategy, mode, objective):
     edges = chain_edges(net)
     c = cfg(strategy=strategy, mode=mode, objective=objective)
     a = optimize_network(net, edges, arch, c)
-    b = optimize_network(net, edges, arch,
-                         dataclasses.replace(c, use_engine=False))
+    b = optimize_network_reference(net, edges, arch, c)
     assert_results_identical(a, b)
 
 
@@ -144,8 +142,7 @@ def test_engine_matches_reference_objective_refine(objective):
     c = cfg(mode="transform", objective=objective, refine_passes=1,
             refine_candidates=4)
     a = optimize_network(net, edges, arch, c)
-    b = optimize_network(net, edges, arch,
-                         dataclasses.replace(c, use_engine=False))
+    b = optimize_network_reference(net, edges, arch, c)
     assert_results_identical(a, b)
 
 

@@ -3,7 +3,7 @@ and the 400/404/429 error surface.
 
 Each test binds a real ``MappingHTTPServer`` on an ephemeral loopback
 port and drives it with ``urllib`` — the same stack the CI smoke leg
-and ``bench_serve``'s HTTP phases use — over the restricted space of
+uses — over the restricted space of
 ``test_serve_service.py`` so everything stays in the fast core loop.
 """
 import json

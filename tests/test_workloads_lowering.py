@@ -6,7 +6,7 @@ The lowering layer (``repro.workloads``) introduces new coordinate maps
 fan-out, SSD batched matmuls, cross-attention). Three things must hold:
 
 * **Differential**: the batched engine and the reference path
-  (``use_engine=False``) produce bit-identical ``NetworkResult``s on
+  (``optimize_network_reference``) produce bit-identical ``NetworkResult``s on
   every zoo smoke config x {prefill, decode} — the engine equivalence
   contract extended over the whole lowered zoo, and over every (mode,
   objective) pair on one MoE and one SSM representative.
@@ -20,7 +20,6 @@ fan-out, SSD batched matmuls, cross-attention). Three things must hold:
   trigger pool inference, and the new maps agree with OverlaPIM's
   exhaustive overlap analysis (the C2 oracle).
 """
-import dataclasses
 import math
 import random
 
@@ -32,8 +31,8 @@ from hypothesis import given, settings, strategies as st
 from repro.configs import ARCH_IDS, get_config
 from repro.core import (FullMap, IdentityMap, SearchConfig, WeightMap,
                         describe, dram_pim, matmul, optimize_network,
-                        random_mapping, ready_steps_analytical,
-                        ready_steps_exhaustive)
+                        optimize_network_reference, random_mapping,
+                        ready_steps_analytical, ready_steps_exhaustive)
 from repro.core.search import MODES, OBJECTIVES
 from repro.workloads import lower, moe_capacity, parse_scenario
 
@@ -79,8 +78,7 @@ def test_engine_matches_reference_all_smoke(arch_id, phase):
     desc = describe(f"{arch_id}:{phase}")
     c = cfg()
     a = optimize_network(desc.layers, desc.edges, small_arch(), c)
-    b = optimize_network(desc.layers, desc.edges, small_arch(),
-                         dataclasses.replace(c, use_engine=False))
+    b = optimize_network_reference(desc.layers, desc.edges, small_arch(), c)
     assert_results_identical(a, b)
 
 
@@ -93,8 +91,7 @@ def test_engine_matches_reference_mla(scenario):
     desc = describe(scenario)
     c = cfg()
     a = optimize_network(desc.layers, desc.edges, small_arch(), c)
-    b = optimize_network(desc.layers, desc.edges, small_arch(),
-                         dataclasses.replace(c, use_engine=False))
+    b = optimize_network_reference(desc.layers, desc.edges, small_arch(), c)
     assert_results_identical(a, b)
 
 
@@ -112,8 +109,7 @@ def test_engine_matches_reference_modes_objectives(scenario, mode,
     desc = describe(scenario)
     c = cfg(mode=mode, objective=objective)
     a = optimize_network(desc.layers, desc.edges, small_arch(), c)
-    b = optimize_network(desc.layers, desc.edges, small_arch(),
-                         dataclasses.replace(c, use_engine=False))
+    b = optimize_network_reference(desc.layers, desc.edges, small_arch(), c)
     assert_results_identical(a, b)
 
 
