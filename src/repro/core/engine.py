@@ -88,7 +88,7 @@ _STAT_KEYS = ("tiles_hit", "tiles_miss", "tail_hit", "tail_miss",
               "sepcls_hit", "sepcls_miss", "score_hit", "score_miss",
               "score_pool_hit", "batch_scored", "full_scored",
               "dense_scored", "guard_fallback", "evictions", "perf_hit",
-              "perf_miss")
+              "perf_miss", "draws", "draw_tries")
 # engine-local stage timers (plain float seconds in ``OverlapEngine.times``,
 # published beside ``stats`` as float counters): the batched class-histogram
 # scorer, the closed-form all-``FullMap`` scorer, the dense per-candidate
@@ -1304,7 +1304,8 @@ def optimize_network_engine(layers: Sequence[LayerSpec],
                       strategy=cfg.strategy,
                       phase="backward" if i in backward_part else "forward"):
             with obs.span("search.candidates", layer=i):
-                cands = candidates(layers[i], arch, cfg, salt=i)
+                cands = candidates(layers[i], arch, cfg, salt=i,
+                                   stats=eng.stats)
             if i in backward_part:
                 scores = np.array([eng.score_backward(i, m, edges, chosen,
                                                       cfg.mode,
@@ -1344,7 +1345,8 @@ def optimize_network_engine(layers: Sequence[LayerSpec],
             for i in range(n):
                 rcfg = dataclasses.replace(
                     cfg, n_candidates=cfg.refine_candidates)
-                cands = candidates(layers[i], arch, rcfg, salt=i + 7919)
+                cands = candidates(layers[i], arch, rcfg, salt=i + 7919,
+                                   stats=eng.stats)
                 cands.append(chosen[i])
                 best_m = chosen[i]
                 best_t = result.objective_value(cfg.objective,

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import random
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -243,9 +244,21 @@ def divisors(n: int) -> Tuple[int, ...]:
     return tuple(out)
 
 
-def random_divisor_le(n: int, cap: int, rng: random.Random) -> int:
-    opts = [d for d in divisors(n) if d <= cap]
-    return rng.choice(opts)
+# cap -> {n -> (the divisors of n up to cap, their count, the count's bit
+# length)}: one draw's option table, built once per process; cap None
+# holds all of n's divisors (a temporal slot's draw). A table of integers
+# shared by every search, never a cache of pools or mappings.
+_Table = Tuple[Tuple[int, ...], int, int]
+_OPTIONS: Dict[Optional[int], Dict[int, _Table]] = {}
+
+
+def _options(n: int, cap: Optional[int]) -> _Table:
+    opts = divisors(n)
+    if cap is not None:
+        opts = tuple(d for d in opts if d <= cap)
+    tab = _OPTIONS.setdefault(cap, {})[n] = (
+        opts, len(opts), len(opts).bit_length())
+    return tab
 
 
 # slots: (level_index, spatial?) outer->inner; filled per dim
@@ -268,65 +281,99 @@ def random_mapping(layer: LayerSpec, arch: ArchSpec, rng: random.Random,
     slot + loop permutation per block. ``stream=True`` forces the
     overlap-friendly temporal order (half of candidates by default).
     """
+    return _draw_mapping(layer, arch, rng, max_steps, max_tries, stream)[0]
+
+
+def _draw_mapping(layer: LayerSpec, arch: ArchSpec, rng: random.Random,
+                  max_steps: int = 65536, max_tries: int = 64,
+                  stream: Optional[bool] = None) -> Tuple[Mapping, int]:
+    """``random_mapping`` and the tries it took, rejected ones included.
+
+    Per try and per dim (``DIMS`` order): a coin per spatial slot the dim
+    may split (reductions only at the target), a divisor of the remainder
+    up to the slot's fanout on heads, then a divisor of the remainder for
+    every temporal slot but the innermost, which absorbs the rest. Each
+    divisor draw is ``rng.choice`` over the sorted options with its
+    ``getrandbits`` rejection loop inlined, so the random stream is
+    ``choice``'s; a remainder of 1 spends the draw of a one-element table
+    (``getrandbits(1)`` until it reads 0) and looks nothing up. Factors
+    stay in flat per-level lists; the loop blocks are assembled only for
+    a try within the step bound.
+    """
     t = arch.target_index
-    n_levels = len(arch.levels)
-    for _ in range(max_tries):
-        # factor assignment: dim -> {slot -> factor}
-        per_slot: Dict[Tuple[int, bool], Dict[str, int]] = {
-            s: {} for s in _slot_order(arch)}
-        ok = True
-        for d in DIMS:
-            rem = layer.dim(d)
-            # choose spatial splits top-down first (subject to fanout)
-            for li in range(n_levels - 1):
-                cap = arch.levels[li + 1].fanout
-                if d in REDUCTION_DIMS and li != t:
-                    f = 1
-                elif rng.random() < 0.5:
-                    f = random_divisor_le(rem, cap, rng)
-                else:
-                    f = 1
-                per_slot[(li, True)][d] = f
+    last = len(arch.levels) - 1
+    caps = [arch.levels[li + 1].fanout for li in range(last)]
+    sizes = [layer.dim(d) for d in DIMS]
+    splits = [[li for li in range(last) if d not in REDUCTION_DIMS or li == t]
+              for d in DIMS]
+    coin, bits = rng.random, rng.getrandbits
+    capped = [_OPTIONS.setdefault(cap, {}) for cap in caps]
+    uncapped = _OPTIONS.setdefault(None, {})
+    nd = len(DIMS)
+    for tries in range(1, max_tries + 1):
+        sp = [[1] * nd for _ in range(last)]        # spatial factors
+        tm = [[1] * nd for _ in range(last + 1)]    # temporal factors
+        for di in range(nd):
+            rem = sizes[di]
+            for li in splits[di]:
+                if coin() < 0.5:
+                    if rem == 1:
+                        while bits(1):
+                            pass
+                        continue
+                    opts, n_opts, k = (capped[li].get(rem)
+                                       or _options(rem, caps[li]))
+                    r = bits(k)
+                    while r >= n_opts:
+                        r = bits(k)
+                    f = opts[r]
+                    sp[li][di] = f
+                    rem //= f
+            for li in range(last):
+                if rem == 1:
+                    while bits(1):
+                        pass
+                    continue
+                opts, n_opts, k = uncapped.get(rem) or _options(rem, None)
+                r = bits(k)
+                while r >= n_opts:
+                    r = bits(k)
+                f = opts[r]
+                tm[li][di] = f
                 rem //= f
-            # distribute the remainder across temporal slots
-            for li in range(n_levels):
-                if li == n_levels - 1:
-                    f = rem  # innermost absorbs the rest
-                else:
-                    f = random_divisor_le(rem, rem, rng)
-                per_slot[(li, False)][d] = f
-                rem //= f
-            if rem != 1:
-                ok = False
-                break
-        if not ok:
-            continue
-        # fanout constraints (joint across dims) + step bound, with repair:
-        for li in range(n_levels - 1):
-            cap = arch.levels[li + 1].fanout
-            sl = per_slot[(li, True)]
-            dims_sorted = sorted(sl, key=lambda d: -sl[d])
-            while _prod(sl.values()) > cap:
-                dd = dims_sorted[0]
-                # demote largest spatial factor to temporal at same level
-                per_slot[(li, False)][dd] *= sl[dd]
-                sl[dd] = 1
-                dims_sorted = sorted(sl, key=lambda d: -sl[d])
+            tm[last][di] = rem
+        # fanout repair (joint across dims): demote the largest spatial
+        # factor, first in DIMS order among equals, to temporal at the
+        # same level until the level fits
+        for li in range(last):
+            row = sp[li]
+            p = math.prod(row)
+            while p > caps[li]:
+                f = max(row)
+                di = row.index(f)
+                tm[li][di] *= f
+                row[di] = 1
+                p //= f
         n_steps = 1
         for li in range(t + 1):
-            n_steps *= _prod(per_slot[(li, False)].values())
+            n_steps *= math.prod(tm[li])
         if n_steps > max_steps:
             continue
-        do_stream = stream if stream is not None else (rng.random() < 0.5)
+        do_stream = stream if stream is not None else (coin() < 0.5)
+        per_slot: Dict[Tuple[int, bool], Dict[str, int]] = {}
+        for li in range(last + 1):
+            per_slot[(li, False)] = dict(zip(DIMS, tm[li]))
+            if li < last:
+                per_slot[(li, True)] = dict(zip(DIMS, sp[li]))
         blocks = _assemble_blocks(arch, per_slot, rng, stream=do_stream)
         m = Mapping(layer=layer, arch=arch, blocks=blocks)
         try:
             m.validate()
         except ValueError:
             continue
-        return m
+        return m, tries
     # fall back to a deterministic valid mapping
-    return heuristic_mapping(layer, arch)
+    return heuristic_mapping(layer, arch), max_tries
 
 
 def _prod(xs: Iterable[int]) -> int:

@@ -23,7 +23,7 @@ import numpy as np
 
 from .. import obs
 from .arch import ArchSpec
-from .mapping import Mapping, heuristic_mapping, random_mapping
+from .mapping import Mapping, _draw_mapping, heuristic_mapping
 from .overlap import (Edge, overlapped_end, ready_steps_analytical,
                       ready_steps_exhaustive, schedule_with_ready,
                       stream_tail_fraction)
@@ -220,15 +220,25 @@ def evaluate_chain(mappings: Sequence[Mapping],
 # ---------------------------------------------------------------------------
 
 def candidates(layer: LayerSpec, arch: ArchSpec,
-               cfg: SearchConfig, salt: int) -> List[Mapping]:
+               cfg: SearchConfig, salt: int,
+               stats: Optional[Dict[str, int]] = None) -> List[Mapping]:
+    """The layer's pool: the heuristic mapping, then ``n_candidates - 1``
+    seeded random draws, duplicates dropped. ``stats`` (an engine's
+    plain-int ``stats``) gains the draws and the tries they took."""
     rng = random.Random((cfg.seed << 20) ^ salt)
     out = [heuristic_mapping(layer, arch, cfg.max_steps)]
     seen = {out[0].blocks}
+    draws = tries = 0
     for _ in range(cfg.n_candidates - 1):
-        m = random_mapping(layer, arch, rng, cfg.max_steps)
+        m, k = _draw_mapping(layer, arch, rng, cfg.max_steps)
+        draws += 1
+        tries += k
         if m.blocks not in seen:
             seen.add(m.blocks)
             out.append(m)
+    if stats is not None:
+        stats["draws"] += draws
+        stats["draw_tries"] += tries
     return out
 
 

@@ -588,6 +588,30 @@ def test_full_scored_counts_closed_form_pools(network, full_layers):
     assert ("engine.full_scored" in got) is full_layers
 
 
+
+@pytest.mark.parametrize("refine_passes", [0, 1])
+def test_draw_counters_count_every_pool_draw(refine_passes):
+    """``engine.draws`` counts the random draws of every pool the search
+    draws (``n_candidates - 1`` per layer, and ``refine_candidates - 1``
+    per layer per refine pass); ``engine.draw_tries`` counts the tries
+    they took, rejected ones included. Both are published as they stand
+    in ``eng.stats``."""
+    layers = _conv_chain()
+    cfg = SearchConfig(n_candidates=8, seed=0, max_steps=512,
+                       mode="transform", refine_passes=refine_passes,
+                       refine_candidates=4)
+    eng = OverlapEngine()
+    optimize_network_engine(layers, chain_edges(layers), _small_arch(),
+                            cfg, engine=eng)
+    assert eng.stats["draws"] == len(layers) * (
+        cfg.n_candidates - 1 + refine_passes * (cfg.refine_candidates - 1))
+    assert eng.stats["draw_tries"] >= eng.stats["draws"] > 0
+    reg = Registry()
+    eng.publish_metrics(registry=reg)
+    got = reg.snapshot()["counters"]
+    assert got["engine.draws"] == eng.stats["draws"]
+    assert got["engine.draw_tries"] == eng.stats["draw_tries"]
+
 def test_publish_metrics_times_follow_their_counts():
     """``engine.score_batch_s``/``score_full_s``/``score_dense_s`` are
     published once per delta, and each is positive exactly when its
