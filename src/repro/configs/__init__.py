@@ -1,4 +1,4 @@
-"""Architecture registry: the zoo's 11 configs + input-shape cells.
+"""Architecture registry: the zoo's 12 configs + input-shape cells.
 
 Every config cites its public source (see per-file docstrings). Use
 ``get_config(arch_id)`` for the full config and
@@ -25,6 +25,7 @@ ARCH_IDS = (
     "whisper_base",
     "llava_next_34b",
     "deepseek_v2",
+    "nemotron_3_nano",
 )
 
 # dashed aliases as listed in the assignment

@@ -87,15 +87,16 @@ _STAT_KEYS = ("tiles_hit", "tiles_miss", "tail_hit", "tail_miss",
               "ready_full", "ready_cmap",
               "sepcls_hit", "sepcls_miss", "score_hit", "score_miss",
               "score_pool_hit", "batch_scored", "full_scored",
-              "dense_scored", "guard_fallback", "evictions", "perf_hit",
-              "perf_miss", "draws", "draw_tries")
+              "dense_scored", "mixed_scored", "guard_fallback", "evictions",
+              "perf_hit", "perf_miss", "draws", "draw_tries")
 # engine-local stage timers (plain float seconds in ``OverlapEngine.times``,
 # published beside ``stats`` as float counters): the batched class-histogram
 # scorer, the closed-form all-``FullMap`` scorer, the dense per-candidate
-# path with its ready-step pass, and, inside that pass, the
-# generic-coordinate-map ready matrices
+# path with its ready-step pass, and, inside that path, the layers whose
+# edges mix ``FullMap`` with an exact map and the generic-coordinate-map
+# ready matrices
 _TIME_KEYS = ("score_batch_s", "score_full_s", "score_dense_s",
-              "ready_cmap_s")
+              "score_mixed_s", "ready_cmap_s")
 
 
 def _unique_inverse(codes: np.ndarray, bound: int):
@@ -1177,7 +1178,8 @@ class OverlapEngine:
             scored = [None] * len(sub)
         # the dense stage: the generic ready-step pass and the
         # per-candidate loop, timed once per call and counted only when
-        # some candidate of this call was scored densely
+        # some candidate of this call was scored densely; the same pair
+        # also times the layers that mix FullMap with exact edges
         n_dense = self.stats["dense_scored"]
         t0 = time.perf_counter()
         if edges[i] and not (fast or full):
@@ -1199,7 +1201,12 @@ class OverlapEngine:
                     has_consumer, pids)
             self._cur.score[skey] = (prods, sc)
         if self.stats["dense_scored"] != n_dense:
-            self.times["score_dense_s"] += time.perf_counter() - t0
+            dt = time.perf_counter() - t0
+            self.times["score_dense_s"] += dt
+            if len({type(e.cmap) is FullMap for e in edges[i]}) == 2:
+                self.times["score_mixed_s"] += dt
+                self.stats["mixed_scored"] += \
+                    self.stats["dense_scored"] - n_dense
         self._cur.score[pkey] = (prods, out.copy())
         return out
 

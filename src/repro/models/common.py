@@ -31,7 +31,7 @@ class ModelConfig:
     d_ff: int = 0
     head_dim: int = 0
     norm: str = "rmsnorm"        # rmsnorm | layernorm_np (OLMo)
-    mlp: str = "swiglu"          # swiglu | gelu
+    mlp: str = "swiglu"          # swiglu | gelu | relu2 (non-gated relu^2)
     rope_theta: float = 10_000.0
     use_rope: bool = True
     tie_embeddings: bool = False
@@ -39,6 +39,9 @@ class ModelConfig:
     n_experts: int = 0
     top_k: int = 0
     n_shared_experts: int = 0
+    # width of each shared expert where it differs from the routed
+    # experts' ``d_ff`` (Nemotron-H ``moe_shared_expert_intermediate_size``)
+    d_ff_shared: int = 0
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
     # routing/capacity is computed per data shard (set to the mesh's data
@@ -85,8 +88,16 @@ class ModelConfig:
     ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    # Mamba-2 head count where the modelling code fixes it, so that
+    # ``d_inner = ssm_n_heads * ssm_head_dim`` rather than
+    # ``ssm_expand * d_model`` (Nemotron-H ``mamba_num_heads``)
+    ssm_n_heads: int = 0
     # hybrid (Zamba-2): one shared attention block applied every k layers
     attn_every: int = 0
+    # one mixer per layer, in order (Nemotron-H ``hybrid_override_pattern``):
+    # "M" Mamba-2, "E" MoE without attention, "*" attention without MLP;
+    # empty means the family's fixed block
+    layer_pattern: str = ""
     # encoder-decoder (Whisper backbone)
     enc_layers: int = 0
     enc_frames: int = 1500
@@ -112,6 +123,8 @@ class ModelConfig:
 
     @property
     def d_inner(self) -> int:
+        if self.ssm_n_heads:
+            return self.ssm_n_heads * self.ssm_head_dim
         return self.ssm_expand * self.d_model
 
     @property

@@ -2,8 +2,10 @@
 
 ``audio`` (encoder-decoder) dispatches to ``encdec``; everything else to
 ``lm``. All functions are pure and jit-friendly. The substrate builds
-GQA/MQA attention only: a config with multi-head latent attention
-(``kv_lora_rank > 0``, DeepSeek-V2) is refused rather than built as GQA
+GQA/MQA attention and the fixed block of each family only: a config
+with multi-head latent attention (``kv_lora_rank > 0``, DeepSeek-V2) or
+with a layer pattern of one mixer per layer (Nemotron-H's Mamba-2, MoE
+and attention layers) is refused rather than built as something else
 under its name; such a config is served by ``repro.workloads`` alone.
 """
 from __future__ import annotations
@@ -20,12 +22,18 @@ PyTree = Any
 
 def _check_buildable(cfg: ModelConfig) -> None:
     """Raise ``NotImplementedError`` for a config the substrate cannot
-    build faithfully (latent attention). The constructors of parameters
-    and caches call it, so nothing downstream runs on such a config."""
+    build faithfully (latent attention, a layer pattern). The
+    constructors of parameters and caches call it, so nothing downstream
+    runs on such a config."""
     if cfg.kv_lora_rank:
         raise NotImplementedError(
             f"{cfg.arch_id}: the LM substrate has no multi-head latent "
             "attention (kv_lora_rank > 0); lower it with repro.workloads")
+    if cfg.layer_pattern:
+        raise NotImplementedError(
+            f"{cfg.arch_id}: the LM substrate has no layer pattern "
+            f"({cfg.layer_pattern!r}: Mamba-2, MoE without attention, "
+            "attention without MLP); lower it with repro.workloads")
 
 
 def init_params(cfg: ModelConfig, key) -> PyTree:

@@ -1,6 +1,6 @@
 """LLM workload lowering: the model zoo as overlap-searchable networks.
 
-``repro.models``/``repro.configs`` define ten LM architectures as JAX
+``repro.models``/``repro.configs`` define the zoo's LM architectures as JAX
 programs; ``repro.core`` searches PIM mappings over 7D loop-nest
 networks. This package is the bridge: ``lower`` turns one ``ModelConfig``
 block into ``LayerSpec`` chains + dependency ``Edge``s, and ``scenarios``

@@ -22,6 +22,11 @@ Conventions (DESIGN.md Section 15):
   + one decoder block. ``blocks=N`` chains N copies of the repeating
   tranche for inter-block overlap studies. Whole-model totals scale the
   per-block result by the block count (``run.py workloads`` prints both).
+* **Layer patterns.** A config with a ``layer_pattern`` (Nemotron-H's
+  ``hybrid_override_pattern``) has one mixer per layer, so ``blocks=N``
+  lowers its first N pattern layers: ``M`` a Mamba-2 mixer
+  (``_ssd_block``), ``E`` an MoE layer without attention (``_moe_ffn``),
+  ``*`` an attention layer without MLP (``_self_attention``).
 * **Exclusions.** Elementwise work is not lowered: norms, softmax,
   rotary embedding, activation functions, the router's top-k
   gate/select, depthwise causal convs (per-channel, MAC-free in the 7D
@@ -98,7 +103,8 @@ def moe_capacity(cfg: ModelConfig, tokens: int) -> int:
 def _ffn(b: NetBuilder, cfg: ModelConfig, inputs: Sequence[Producer],
          prefix: str, tokens: int, d_in: int, d_ff: int) -> List[Producer]:
     """One MLP: swiglu = gate/up in parallel + down consuming both (the
-    elementwise gate multiply is excluded); gelu = ffn1 -> ffn2."""
+    elementwise gate multiply is excluded); gelu and relu2 (non-gated
+    relu^2) = ffn1 -> ffn2."""
     deps = [_edge(i, k) for i, k in inputs]
     if cfg.mlp == "swiglu":
         gate = b.add(matmul(f"{prefix}ffn_gate", tokens, d_in, d_ff), deps)
@@ -261,41 +267,49 @@ def expert_range(cfg: ModelConfig, share: Tuple[int, int] = (0, 1)
     return range(s * per, (s + 1) * per)
 
 
-def _moe_block(b: NetBuilder, cfg: ModelConfig,
-               inputs: Sequence[Producer], prefix: str,
-               q_len: int, kv_len: int,
-               share: Tuple[int, int] = (0, 1)) -> List[Producer]:
-    """Attention + router + shared experts + top-k routed expert fan-out.
+def _moe_ffn(b: NetBuilder, cfg: ModelConfig,
+             inputs: Sequence[Producer], prefix: str, q_len: int,
+             share: Tuple[int, int] = (0, 1)) -> List[Producer]:
+    """Router + shared experts + top-k routed expert fan-out over
+    ``inputs``.
 
     The router is a plain ``tokens x d_model x n_experts`` matmul (its
-    softmax/top-k select is elementwise, excluded). Shared experts see
-    every token in order (exact identity edges); each routed expert of
-    this device's ``share`` (``expert_range``; all ``n_experts`` when
+    softmax/sigmoid and top-k select are elementwise, excluded). Shared
+    experts, at ``d_ff_shared`` where set and else ``d_ff``, see every
+    token in order (exact identity edges); each routed expert of this
+    device's ``share`` (``expert_range``; all ``n_experts`` when
     unshared) is lowered at its ``moe_capacity`` slot count with
     ``FullMap`` fan-out edges from both the router (dispatch waits on
-    routing values) and the attention output (token gather). The router
-    and the capacity keep the whole layer's ``n_experts``; attention and
-    shared experts are whole on every device, and the exchange between
-    devices is not lowered. The combine is a scatter-add, so expert
-    outputs re-enter downstream consumers as ``full`` producers
-    (fan-in)."""
+    routing values) and the inputs (token gather). The router and the
+    capacity keep the whole layer's ``n_experts``; shared experts are
+    whole on every device, and the exchange between devices is not
+    lowered. The combine is a scatter-add, so expert outputs re-enter
+    downstream consumers as ``full`` producers (fan-in)."""
     experts = expert_range(cfg, share)
-    attn = _self_attention(b, cfg, inputs, prefix, q_len, kv_len)
-    attn_deps = [_edge(i, k) for i, k in attn]
     router = b.add(matmul(f"{prefix}router", q_len, cfg.d_model,
-                          cfg.n_experts), attn_deps)
+                          cfg.n_experts), [_edge(i, k) for i, k in inputs])
     outs: List[Producer] = []
     for s in range(cfg.n_shared_experts):
-        outs += _ffn(b, cfg, attn, f"{prefix}shared{s}.", q_len,
-                     cfg.d_model, cfg.d_ff)
+        outs += _ffn(b, cfg, inputs, f"{prefix}shared{s}.", q_len,
+                     cfg.d_model, cfg.d_ff_shared or cfg.d_ff)
     cap = moe_capacity(cfg, q_len)
     fan_out: List[Producer] = [(router, "full")] + \
-        [(i, "full") for i, _ in attn]
+        [(i, "full") for i, _ in inputs]
     for e in experts:
         (down, _), = _ffn(b, cfg, fan_out, f"{prefix}exp{e}.", cap,
                           cfg.d_model, cfg.d_ff)
         outs.append((down, "full"))
     return outs
+
+
+def _moe_block(b: NetBuilder, cfg: ModelConfig,
+               inputs: Sequence[Producer], prefix: str,
+               q_len: int, kv_len: int,
+               share: Tuple[int, int] = (0, 1)) -> List[Producer]:
+    """Attention, then the MoE feed-forward (``_moe_ffn``) reading it;
+    attention is whole on every device."""
+    attn = _self_attention(b, cfg, inputs, prefix, q_len, kv_len)
+    return _moe_ffn(b, cfg, attn, prefix, q_len, share)
 
 
 def _ssd_block(b: NetBuilder, cfg: ModelConfig,
@@ -419,6 +433,36 @@ def _audio_net(b: NetBuilder, cfg: ModelConfig, phase: str,
         inputs = _ffn(b, cfg, cross_out, pre, q_len, cfg.d_model, cfg.d_ff)
 
 
+#: the characters of a ``layer_pattern``: "M" Mamba-2 (``_ssd_block``),
+#: "E" MoE without attention (``_moe_ffn``), "*" attention without MLP
+#: (``_self_attention``)
+MIXERS = "ME*"
+
+
+def pattern_layers(cfg: ModelConfig, blocks: int) -> str:
+    """The first ``blocks`` layers of ``cfg``'s pattern; ValueError past
+    its end or for a character that names no mixer."""
+    pat = cfg.layer_pattern
+    bad = sorted(set(pat) - set(MIXERS))
+    if bad:
+        raise ValueError(f"{cfg.arch_id}: layer pattern characters {bad} "
+                         f"name no mixer ({MIXERS!r})")
+    if blocks > len(pat):
+        raise ValueError(f"{cfg.arch_id}: {blocks} layers asked of a "
+                         f"{len(pat)}-layer pattern")
+    return pat[:blocks]
+
+
+def default_blocks(cfg: ModelConfig) -> int:
+    """Blocks a scenario name without ``xN`` lowers: one tranche, or for
+    a patterned config the shortest prefix of its pattern that holds
+    every mixer kind of the pattern."""
+    pat = cfg.layer_pattern
+    if not pat:
+        return 1
+    return max(pat.index(k) for k in set(pat)) + 1
+
+
 def lower(cfg: ModelConfig, phase: str = "prefill", seq: int = 2048,
           kv_len: int = 1024, blocks: int = 1,
           share: Tuple[int, int] = (0, 1)
@@ -433,14 +477,17 @@ def lower(cfg: ModelConfig, phase: str = "prefill", seq: int = 2048,
     routed expert fan-out, the first ``n_dense_layers`` of the chain
     dense blocks at ``d_ff_dense``, ``ssm`` -> SSD skeleton, ``hybrid``
     -> one SSD block + the shared attention block per tranche,
-    ``audio`` -> whisper stem/encoder/decoder. ``share`` = (s, n): this
-    device holds routed-expert share s of n (``expert_range``)."""
+    ``audio`` -> whisper stem/encoder/decoder; a config with a
+    ``layer_pattern`` -> its first ``blocks`` pattern layers, one mixer
+    each (``MIXERS``). ``share`` = (s, n): this device holds
+    routed-expert share s of n (``expert_range``)."""
     if phase not in PHASES:
         raise ValueError(f"phase must be one of {PHASES}, got {phase!r}")
     if seq < 1 or kv_len < 1 or blocks < 1:
         raise ValueError(f"seq/kv_len/blocks must be >= 1, got "
                          f"{seq}/{kv_len}/{blocks}")
     expert_range(cfg, share)
+    pattern = pattern_layers(cfg, blocks) if cfg.layer_pattern else ""
     b = NetBuilder()
     fam = cfg.family
     if fam == "audio":
@@ -453,7 +500,14 @@ def lower(cfg: ModelConfig, phase: str = "prefill", seq: int = 2048,
     q_len, kv = (seq, seq) if phase == "prefill" else (1, kv_len)
     for i in range(blocks):
         pre = f"b{i}." if blocks > 1 else ""
-        if fam == "moe" and i < cfg.n_dense_layers:
+        mixer = pattern[i] if pattern else ""
+        if mixer == "M":
+            inputs = _ssd_block(b, cfg, inputs, pre, phase, q_len)
+        elif mixer == "E":
+            inputs = _moe_ffn(b, cfg, inputs, pre, q_len, share)
+        elif mixer == "*":
+            inputs = _self_attention(b, cfg, inputs, pre, q_len, kv)
+        elif fam == "moe" and i < cfg.n_dense_layers:
             inputs = _dense_block(b, cfg, inputs, pre, q_len, kv,
                                   cfg.d_ff_dense)
         elif fam == "moe":

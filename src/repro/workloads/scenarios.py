@@ -17,6 +17,14 @@ holds one of N equal shares of each layer's routed experts, see
 is the prompt length (prefill) or KV/context length (decode) and
 ``blocks`` chains that many tranche blocks. Defaults and the canonical
 per-arch names live in ``list_scenarios``.
+
+A config with a layer pattern (one mixer per layer, Nemotron-H) counts
+``blocks`` in pattern layers: ``xN`` is the pattern's first N layers,
+refused past its end. A name without ``xN`` lowers the shortest prefix
+of the pattern that holds every mixer kind in it
+(``lowering.default_blocks``): ``nemotron_3_nano:decode@32768`` is
+``MEMEM*``, six layers; ``...x7`` adds the MoE layer that closes the
+first period.
 """
 from __future__ import annotations
 
@@ -28,7 +36,8 @@ from ..configs import ARCH_IDS, get_config
 from ..core.interface import NetworkDesc
 from ..core.workload import LayerSpec
 from ..models.common import ModelConfig
-from .lowering import PHASES, expert_range, lower
+from .lowering import (PHASES, default_blocks, expert_range, lower,
+                       pattern_layers)
 
 #: default lengths of scenario names that omit ``@length``
 DEFAULT_PREFILL_SEQ = 2048
@@ -57,7 +66,8 @@ class Scenario:
     @property
     def name(self) -> str:
         """Canonical round-trippable scenario string."""
-        suffix = "" if self.blocks == 1 else f"x{self.blocks}"
+        suffix = "" if self.blocks == default_blocks(self.config()) \
+            else f"x{self.blocks}"
         arch = self.arch_id + ("_smoke" if self.smoke else "") \
             + ("" if self.ep == 1 else f"_ep{self.ep}")
         return f"{arch}:{self.phase}@{self.length}{suffix}"
@@ -97,8 +107,9 @@ def parse_scenario(name: str, *, seq: Optional[int] = None,
                        f"{list(ARCH_IDS)} (grammar: "
                        "'<arch>[:phase][@length][xblocks]')")
     arch_id, smoke, ep = arch
+    cfg = get_config(arch_id, smoke=smoke)
     if ep != 1:
-        expert_range(get_config(arch_id, smoke=smoke), (0, ep))
+        expert_range(cfg, (0, ep))
     phase = m.group("phase") or "prefill"
     if phase not in PHASES:
         raise ValueError(f"scenario {name!r}: phase must be one of "
@@ -113,10 +124,12 @@ def parse_scenario(name: str, *, seq: Optional[int] = None,
         if length is None:
             length = SMOKE_DECODE_KV if smoke else DEFAULT_DECODE_KV
     n_blocks = blocks if blocks is not None else \
-        int(m.group("blocks") or 1)
+        int(m.group("blocks") or default_blocks(cfg))
     if length < 1 or n_blocks < 1:
         raise ValueError(f"scenario {name!r}: length and blocks must be "
                          f">= 1, got {length}/{n_blocks}")
+    if cfg.layer_pattern:
+        pattern_layers(cfg, n_blocks)
     return Scenario(arch_id=arch_id, smoke=smoke, phase=phase,
                     length=length, blocks=n_blocks, ep=ep)
 

@@ -12,8 +12,10 @@ from repro.models import model_zoo
 from repro.models.inputs import make_decode_tokens, make_train_batch
 
 B, S = 2, 32
-#: the archs the LM substrate builds (latent attention is lowered only)
-LM_ARCHS = [a for a in ARCH_IDS if not get_config(a).kv_lora_rank]
+#: the archs the LM substrate builds (latent attention and layer
+#: patterns are lowered only)
+LM_ARCHS = [a for a in ARCH_IDS if not (get_config(a).kv_lora_rank
+                                        or get_config(a).layer_pattern)]
 
 
 @pytest.fixture(scope="module")
@@ -187,3 +189,17 @@ def test_compile_cache_dir(monkeypatch, tmp_path):
             repo, ".jax_cache")
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+@pytest.mark.parametrize("smoke", [True, False])
+def test_layer_pattern_config_is_refused(smoke):
+    """Nemotron-H's pattern of Mamba-2, attention-free MoE and MLP-free
+    attention layers is not built as a zamba2 hybrid under its name:
+    parameters, their shapes and a cache are all refused."""
+    cfg = get_config("nemotron_3_nano", smoke=smoke)
+    assert "nemotron_3_nano" not in LM_ARCHS
+    for build in (lambda: model_zoo.init_params(cfg, jax.random.PRNGKey(0)),
+                  lambda: model_zoo.param_shapes(cfg),
+                  lambda: model_zoo.init_cache(cfg, B, S)):
+        with pytest.raises(NotImplementedError, match="layer pattern"):
+            build()

@@ -589,6 +589,41 @@ def test_full_scored_counts_closed_form_pools(network, full_layers):
 
 
 
+@pytest.mark.parametrize("network,mixed_layers", [
+    ("nemotron_3_nano_smoke:decode@16x7", True),
+    ("deepseek_v2_smoke_ep2:decode@16x2", True),
+    ("resnet18", False),
+    ("conv_chain", False)])
+def test_mixed_scored_counts_dense_layers_of_mixed_edges(network,
+                                                         mixed_layers):
+    """``engine.mixed_scored``/``score_mixed_s`` count and time the
+    dense scoring of layers whose edges mix FullMap with an exact map
+    (a Mamba-2 projection reading the MoE combine beside the shared
+    expert, a decode score reading the cache append beside its queries):
+    a part of ``dense_scored``/``score_dense_s``, zero on networks
+    without FullMap edges."""
+    if network == "conv_chain":
+        layers = _conv_chain()
+        edges = chain_edges(layers)
+    else:
+        desc = describe(network)
+        layers, edges = desc.layers, desc.edges
+    eng = OverlapEngine()
+    cfg = SearchConfig(n_candidates=4, seed=0, max_steps=256,
+                       mode="transform")
+    optimize_network_engine(layers, edges, _small_arch(), cfg, engine=eng)
+    assert (eng.stats["mixed_scored"] > 0) is mixed_layers
+    assert (eng.times["score_mixed_s"] > 0) is mixed_layers
+    assert eng.stats["mixed_scored"] <= eng.stats["dense_scored"]
+    assert eng.times["score_mixed_s"] <= eng.times["score_dense_s"]
+    reg = Registry()
+    eng.publish_metrics(registry=reg)
+    got = reg.snapshot()["counters"]
+    assert got.get("engine.mixed_scored", 0) == eng.stats["mixed_scored"]
+    assert got.get("engine.score_mixed_s", 0) == eng.times["score_mixed_s"]
+    assert ("engine.score_mixed_s" in got) is mixed_layers
+
+
 @pytest.mark.parametrize("refine_passes", [0, 1])
 def test_draw_counters_count_every_pool_draw(refine_passes):
     """``engine.draws`` counts the random draws of every pool the search

@@ -88,12 +88,14 @@ def test_stem_pool_resnet():
 # -- config registry ----------------------------------------------------------
 
 def test_all_archs_and_cells_accounted():
-    assert len(ARCH_IDS) == 11
+    assert len(ARCH_IDS) == 12
     assert len(SHAPES) == 4
     full = cells(include_skipped=True)
-    assert len(full) == 44
+    assert len(full) == 48
     live = cells(include_skipped=False)
-    assert len(live) == 35  # 9 long_500k skips for full-attention archs
+    assert len(live) == 39  # 9 long_500k skips for full-attention archs
+    ok, why = cell_status("nemotron_3_nano", "long_500k")
+    assert ok                # hybrid: six attention layers of 52
     ok, why = cell_status("mamba2_780m", "long_500k")
     assert ok
     ok, why = cell_status("granite_8b", "long_500k")
@@ -112,6 +114,7 @@ def test_config_fields_match_assignment(arch):
         "whisper_base": (6, 512, 51865),
         "llava_next_34b": (60, 7168, 64000),
         "deepseek_v2": (60, 5120, 102400),
+        "nemotron_3_nano": (52, 2688, 131072),
     }[arch]
     assert (cfg.n_layers, cfg.d_model, cfg.vocab) == expect
     smoke = get_config(arch, smoke=True)

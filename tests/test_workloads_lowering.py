@@ -20,6 +20,9 @@ fan-out, SSD batched matmuls, cross-attention). Three things must hold:
   trigger pool inference, and the new maps agree with OverlaPIM's
   exhaustive overlap analysis (the C2 oracle).
 """
+import dataclasses
+import hashlib
+import json
 import math
 import random
 
@@ -95,6 +98,22 @@ def test_engine_matches_reference_mla(scenario):
     assert_results_identical(a, b)
 
 
+@pytest.mark.parametrize("scenario", ["nemotron_3_nano_smoke:decode@16x7",
+                                      "nemotron_3_nano_smoke:prefill@16x7",
+                                      "nemotron_3_nano_smoke_ep4:decode@16x7",
+                                      "nemotron_3_nano_smoke_ep2:prefill@8x3"])
+def test_engine_matches_reference_layer_pattern(scenario):
+    """A layer pattern's Mamba-2, attention-free MoE and MLP-free
+    attention layers, the combine read through FullMap beside the shared
+    expert's identity, and an expert share: engine and reference agree
+    bit for bit."""
+    desc = describe(scenario)
+    c = cfg()
+    a = optimize_network(desc.layers, desc.edges, small_arch(), c)
+    b = optimize_network_reference(desc.layers, desc.edges, small_arch(), c)
+    assert_results_identical(a, b)
+
+
 @pytest.mark.parametrize("objective", OBJECTIVES)
 @pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize(
@@ -117,7 +136,7 @@ def test_engine_matches_reference_modes_objectives(scenario, mode,
 # Golden MAC accounting (analytic formulas, derived independently)
 # ---------------------------------------------------------------------------
 
-_FAC = {"swiglu": 3, "gelu": 2}
+_FAC = {"swiglu": 3, "gelu": 2, "relu2": 2}
 
 
 def _ffn_macs(c, tokens):
@@ -162,19 +181,28 @@ def _self_attn_macs(c, q, kv):
     return _attn_macs(c, q, kv, q if q == kv else 1)
 
 
-def _moe_macs(c, q, kv, shares=1):
+def _moe_ffn_macs(c, q, shares=1):
+    """Router, shared experts at their own width (``d_ff_shared``, else
+    ``d_ff``) and this share's routed experts at capacity."""
     cap = max(1, math.ceil(q / max(c.moe_shards, 1) * c.top_k
                            / c.n_experts * c.capacity_factor))
-    return (_self_attn_macs(c, q, kv)
-            + q * c.d_model * c.n_experts
-            + c.n_shared_experts * _ffn_macs(c, q)
+    return (q * c.d_model * c.n_experts
+            + c.n_shared_experts * _FAC[c.mlp] * q * c.d_model
+            * (c.d_ff_shared or c.d_ff)
             + c.n_experts // shares * _FAC[c.mlp] * cap * c.d_model
             * c.d_ff)
 
 
+def _moe_macs(c, q, kv, shares=1):
+    return _self_attn_macs(c, q, kv) + _moe_ffn_macs(c, q, shares)
+
+
 def _ssd_macs(c, phase, tokens):
-    d, di = c.d_model, c.d_inner
-    h, p, g, n = c.ssm_heads, c.ssm_head_dim, c.ssm_groups, c.ssm_state
+    """Mamba-2: inner width ``mamba_num_heads x head dim`` where the
+    config fixes the head count, else ``expand x d_model``."""
+    d, p = c.d_model, c.ssm_head_dim
+    di = c.ssm_n_heads * p if c.ssm_n_heads else c.ssm_expand * d
+    h, g, n = di // p, c.ssm_groups, c.ssm_state
     proj = tokens * d * (2 * di + 2 * g * n + h)
     if phase == "prefill":
         ck = min(c.ssm_chunk, tokens)
@@ -218,6 +246,10 @@ def analytic_macs(c, phase, length, blocks=1, shares=1):
                  + c.img_tokens * c.d_model ** 2)
         length = length + c.img_tokens
     q, kv = (length, length) if phase == "prefill" else (1, length)
+    if c.layer_pattern:
+        per = {"M": _ssd_macs(c, phase, q), "E": _moe_ffn_macs(c, q, shares),
+               "*": _self_attn_macs(c, q, kv)}
+        return sum(per[k] for k in c.layer_pattern[:blocks])
     if fam == "moe":
         dense = min(blocks, c.n_dense_layers)
         return (dense * (_self_attn_macs(c, q, kv)
@@ -249,7 +281,7 @@ def test_golden_mac_accounting(arch_id, phase, smoke):
 
 @pytest.mark.parametrize("arch_id", ["deepseek_moe_16b", "zamba2_1_2b",
                                      "whisper_base", "llava_next_34b",
-                                     "deepseek_v2"])
+                                     "deepseek_v2", "nemotron_3_nano"])
 def test_golden_macs_multi_block(arch_id):
     """blocks=N scales the repeating tranche only — frontends (vision
     patch-embed, whisper stem+encoder) are lowered once."""
@@ -271,6 +303,57 @@ def test_golden_macs_deepseek_v2_expert_share(phase, length):
         analytic_macs(c, phase, length, blocks=5, shares=8)
     if phase == "decode":
         assert len(layers) == 14 + 4 * 78
+
+
+@pytest.mark.parametrize("phase,length", [("decode", 32768),
+                                          ("prefill", 512)])
+def test_golden_macs_nemotron_period_expert_share(phase, length):
+    """The benchmark's shape: the pattern's first period MEMEM*E holding
+    one of sixteen routed-expert shares, at the published widths."""
+    c = get_config("nemotron_3_nano")
+    layers, edges = lower(c, phase, seq=length, kv_len=length, blocks=7,
+                          share=(0, 16))
+    assert sum(l.macs for l in layers) == \
+        analytic_macs(c, phase, length, blocks=7, shares=16)
+    kinds = {type(e.cmap).__name__ for es in edges for e in es}
+    assert kinds <= {"IdentityMap", "HeadFoldMap", "HeadUnfoldMap",
+                     "WeightMap", "FullMap"}
+    if phase == "decode":
+        # by hand: a Mamba-2 layer 2688 x (2*4096 + 2*8*128 + 64) + 2 x
+        # 64*128*64 + 4096 x 2688; an MoE layer 2688 x 128 + 2 x 2688 x
+        # 3712 + 8 x 2 x 2688 x 1856; attention 2688 x (4096 + 2*256) +
+        # 2 x 32*32768*128 + 4096 x 2688
+        assert (39_755_776, 100_122_624, 291_831_808) == (
+            analytic_macs(c, phase, length, blocks=1),
+            analytic_macs(c, phase, length, blocks=2, shares=16)
+            - 39_755_776,
+            _self_attn_macs(c, 1, length))
+        assert len(layers) == 3 * 8 + 3 * 19 + 6 == 87
+        assert sum(l.macs for l in layers) == 711_467_008
+
+
+#: sha256 of the lowered layers and edges of the benchmark's MoE
+#: scenarios, frozen before the MoE feed-forward was split from its
+#: attention (``_moe_ffn``): the split must leave them field for field
+_PINNED_NETWORKS = {
+    "granite_moe_1b_a400m:decode@4096x2":
+        (206, "4fc9fb0b61ac85aad903cdf768a4de63"
+              "7691799fd99595055ff4737fd8de0101"),
+    "deepseek_v2_ep8:decode@32768x5":
+        (326, "7e81ed29df92951a34ac5011f3a5c067"
+              "82d60dca3e93be7faeb6ec68756d25e5"),
+}
+
+
+@pytest.mark.parametrize("scenario", sorted(_PINNED_NETWORKS))
+def test_benchmark_moe_networks_are_pinned(scenario):
+    desc = describe(scenario)
+    body = {"layers": [dataclasses.asdict(l) for l in desc.layers],
+            "edges": [[[e.producer, list(e.cmap.key())] for e in es]
+                      for es in desc.edges]}
+    digest = hashlib.sha256(json.dumps(body, sort_keys=True)
+                            .encode()).hexdigest()
+    assert (len(desc.layers), digest) == _PINNED_NETWORKS[scenario]
 
 
 def test_moe_capacity_formula():
@@ -382,6 +465,31 @@ def test_scenario_roundtrip_and_defaults():
     assert parse_scenario("mamba2_780m").phase == "prefill"
     assert parse_scenario("mamba2_780m_smoke:decode").length == 16
     assert parse_scenario("granite-8b-smoke:prefill@64x2").blocks == 2
+
+
+def test_scenario_layer_pattern_blocks():
+    """A patterned config counts blocks in pattern layers; without xN
+    it lowers the shortest prefix holding every mixer kind."""
+    sc = parse_scenario("nemotron_3_nano:decode@32768")
+    assert sc.blocks == 6 and sc.name == "nemotron_3_nano:decode@32768"
+    for name in ("nemotron_3_nano_ep16:decode@32768x7",
+                 "nemotron_3_nano_smoke:prefill@64x1",
+                 "nemotron_3_nano:decode@32768x52"):
+        assert parse_scenario(name).name == name
+    layers = describe("nemotron_3_nano_smoke:decode@16").layers
+    assert {l.name.split(".")[0] for l in layers} == \
+        {f"b{i}" for i in range(6)}
+    assert layers[-1].name == "b5.out_proj"       # MEMEM*: ends on *
+    assert describe("nemotron_3_nano_smoke:decode@16x1").layers[0].name \
+        == "z_proj"
+    with pytest.raises(ValueError):
+        parse_scenario("nemotron_3_nano_smoke:decode@16x8")
+    with pytest.raises(ValueError):
+        lower(get_config("nemotron_3_nano"), "decode", blocks=53)
+    bad = get_config("nemotron_3_nano", smoke=True).with_(
+        layer_pattern="ME-")
+    with pytest.raises(ValueError, match="name no mixer"):
+        lower(bad, "decode", blocks=2)
 
 
 def test_scenario_errors():
